@@ -110,6 +110,8 @@ class PipelineBundle:
     # driver reports one on_round("train", sched, ...) per executed
     # round against this bundle's schedule table
     obs: Any = None
+    # the optimizer train_step applies (an oracle replays the same one)
+    optimizer: Any = None
 
     def state_shardings(self):
         return jax.tree.map(lambda s: NamedSharding(self.mesh, s),
@@ -286,6 +288,8 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
 
             _, vjp = jax.vjp(f_full, w_used, x_saved, cross)
             dW, dx, dcx = vjp((g_in.astype(x_saved.dtype), aux_ct))
+            if tp_axis is not None:
+                dcx = jax.lax.pmean(dcx, tp_axis)
             old = jax.lax.dynamic_index_in_dim(denc_ring[0], bsafe, 0,
                                                keepdims=False)
             dcx = jnp.where(valid, dcx.astype(denc_ring.dtype), old)
@@ -298,8 +302,11 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
             _, vjp = jax.vjp(f_txt, w_used, x_saved)
             dW, dx = vjp((g_in.astype(x_saved.dtype), aux_ct))
 
+        if tp_axis is not None:
+            dW = jax.tree.map(tp_weight_grad, dW, tp_sharded)
         dW = tree_scale(dW, valid.astype(jnp.float32))
         dx = dx * valid.astype(dx.dtype)
+        dx_embeds = dx if tp_axis is None else jax.lax.pmean(dx, tp_axis)
 
         if accumulate:
             if vs == 1:
@@ -331,7 +338,8 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
                 new_w, new_opt = upd_w, upd_o
 
         g_send = jax.lax.ppermute(dx, AXIS_STAGE, bwd_perm) if S > 1 else dx
-        return new_w, new_opt, g_send[None], grad_acc, dx[None], denc_ring
+        return (new_w, new_opt, g_send[None], grad_acc, dx_embeds[None],
+                denc_ring)
 
     # ======================= pspecs =====================================
     _box = {}
@@ -345,6 +353,20 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
     pspecs = _box["pspecs"]
 
     stage_pspec = pspecs["stages"]
+    # With check_vma=False, a vjp inside the shard_map differentiates the
+    # sum of the tp ranks' copies of the loss, and counts each rank's copy
+    # of a tensor-replicated input as a variable of its own.  So a
+    # tensor-sharded weight's cotangent is tp times its gradient, and a
+    # tensor-replicated weight's or activation's gradient is the mean of
+    # the ranks' cotangents.  The dx sent to the previous stage stays per
+    # rank: it is the cotangent of that stage's per-rank output copies.
+    tp_sharded = jax.tree.map(
+        lambda p: AXIS_TENSOR in jax.tree.leaves(tuple(p)), stage_pspec,
+        is_leaf=_is_pspec)
+
+    def tp_weight_grad(ct, sharded):
+        return ct / plan.tp if sharded else jax.lax.pmean(ct, tp_axis)
+
     stash_pspec = (jax.tree.map(lambda p: P(None, *p), stage_pspec,
                                 is_leaf=_is_pspec)
                    if use_ring else {"_": P()})
@@ -660,4 +682,4 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
         train_step=train_step, init_state=init_state,
         state_pspecs=state_pspecs, batch_pspecs=batch_pspecs,
         batch_shapes=batch_shapes, seq_len=seq_len, microbatch_size=mb,
-        obs=obs)
+        obs=obs, optimizer=optimizer)

@@ -7,14 +7,19 @@ score tile hits the MXU as one matmul.  Block defaults are 128-aligned to
 the MXU systolic array; the k-block grid axis is the innermost (sequential
 on TPU) so VMEM scratch carries the running state across k steps.
 
-Grid: (batch, q_heads, Sq/block_q, Sk/block_k).
-BlockSpecs (VMEM tiles):
-  q   (1, block_q, 1, d_head)   index (b, iq)    — reused across all ik
-  k,v (1, block_k, 1, d_head)   index (b, ik, h // group_q)   — GQA: query
-                                 heads map onto their shared KV head
-  out (1, block_q, 1, d_head)   written once at ik == nk-1
+Heads move ahead of the sequence axis outside the kernel, so every tile
+is a (rows, d_head) slab whose last two dims meet the TPU's (8, 128)
+tiling rule (d_head is the array's full last dim, so d_head = 120 works).
 
-Scratch: m, l (block_q,) f32; acc (block_q, d_head) f32.
+Grid: (batch, q_heads, Sq/block_q, Sk/block_k).
+BlockSpecs (VMEM tiles) on the (B, H, S, Dh) layout, batch and head dims
+squeezed (None):
+  q   (block_q, d_head)   index (b, h, iq)    — reused across all ik
+  k,v (block_k, d_head)   index (b, h // group_q, ik)   — GQA: query
+                          heads map onto their shared KV head
+  out (block_q, d_head)   written once at ik == nk-1
+
+Scratch: m, l (block_q, 1) f32; acc (block_q, d_head) f32.
 
 Fully-masked (q, k) block pairs are skipped with pl.when — on hardware
 this prunes ~half the causal grid and all-but-window/block_k of the SWA
@@ -59,9 +64,9 @@ def _flash_kernel(w_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0, :, 0, :]
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        q = q_ref[...]
+        k = k_ref[...]
+        v = v_ref[...]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # (bq, bk)
@@ -73,19 +78,19 @@ def _flash_kernel(w_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         mask &= (window <= 0) | ((qpos - kpos) < jnp.maximum(window, 1))
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot(
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -114,28 +119,27 @@ def flash_attention(q, k, v, *, causal: bool = True, window=-1,
         _flash_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk)
 
-    return pl.pallas_call(
+    # heads ahead of the sequence axis: (B, S, H, Dh) -> (B, H, S, Dh)
+    qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    q_spec = pl.BlockSpec((None, None, block_q, dh),
+                          lambda b_, h_, iq, ik: (b_, h_, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, dh),
+                           lambda b_, h_, iq, ik: (b_, h_ // group, ik, 0))
+    out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, 1, dh),
-                         lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dh),
-                               lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(warr, q, k, v)
+    )(warr, qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)

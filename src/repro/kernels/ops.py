@@ -1,11 +1,17 @@
 """jit'd wrappers + dispatch for the Pallas kernels.
 
-On TPU the kernels run compiled; this CPU container validates them in
-``interpret=True`` mode (the kernel body executes in Python — exact
-semantics, no Mosaic).  ``use_pallas()`` gates the dispatch from
-models/nn.py: by default the XLA-lowerable jnp twins run (fast on CPU and
-inside big jit graphs); set REPRO_USE_PALLAS=1 (or call ``enable(True)``)
-to route attention / WKV through the kernels.
+On a TPU the kernels run compiled.  Anywhere else they can only run in
+Pallas interpret mode (the kernel body executes in Python — exact
+semantics, no Mosaic), and that is the caller's explicit choice:
+``set_interpret(True)`` (the CPU test suite does this).  A kernel
+dispatched off a TPU without that choice raises instead of silently
+running the interpreter.  ``set_interpret(False)`` forces the Mosaic
+lowering, which is how kernels are compiled for a described (not
+attached) TPU.
+
+``use_pallas()`` gates the dispatch from models/nn.py: by default the
+XLA-lowerable jnp twins run; set REPRO_USE_PALLAS=1 (or call
+``enable(True)``) to route attention / WKV through the kernels.
 """
 from __future__ import annotations
 
@@ -34,9 +40,27 @@ def use_pallas() -> bool:
     return os.environ.get("REPRO_USE_PALLAS", "0") == "1"
 
 
+_INTERPRET: Optional[bool] = None
+
+
+def set_interpret(on: Optional[bool]) -> Optional[bool]:
+    """Choose Pallas interpret mode (None: unset).  Returns the old choice."""
+    global _INTERPRET
+    old, _INTERPRET = _INTERPRET, on
+    return old
+
+
 def interpret_mode() -> bool:
-    """Pallas interpret mode whenever we are not actually on TPU."""
-    return jax.default_backend() != "tpu"
+    """The caller's interpret choice; compiled on a TPU when unset."""
+    if _INTERPRET is not None:
+        return _INTERPRET
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"Pallas kernel dispatched on backend {backend!r}: the kernels "
+            "compile only for a TPU.  Call repro.kernels.ops."
+            "set_interpret(True) to run them in interpret mode.")
+    return False
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = -1,
@@ -76,7 +100,7 @@ def mamba_scan(u, dt, A, B, C, D, *, chunk: int = 128,
                ci_block: int = 512):
     """Pads S to the chunk multiple (dt=0 padding is state-neutral)."""
     b, s, ci = u.shape
-    chunk = min(chunk, max(s, 8))
+    chunk = min(chunk, -(-s // 8) * 8)
     ci_block = min(ci_block, ci)
     while ci % ci_block:
         ci_block //= 2

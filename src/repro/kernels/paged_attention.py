@@ -10,11 +10,13 @@ the k/v BlockSpec index maps, so the gather IS the DMA schedule — each
 the flash kernel (kernels/flash_attention.py), with the same running
 (m, l, acc) softmax scratch discipline.
 
-Grid: (batch, kv_heads, n_pages); the page axis is innermost
-("arbitrary" = sequential on TPU) so the VMEM scratch carries the
-running state across pages.  GQA is handled by processing one KV head's
-whole query-head group (G = H // KV) per grid step — the (G, page)
-score tile hits the MXU as one matmul.
+Grid: (batch, n_pages); the page axis is innermost ("arbitrary" =
+sequential on TPU) so the VMEM scratch carries the running state across
+pages.  One grid step loads a page for all KV heads — the (page, KV, Dh)
+tile keeps the pool's own layout and meets the TPU tiling rule for any
+KV count — and walks the heads statically.  GQA is handled by processing
+one KV head's whole query-head group (G = H // KV) at a time — the
+(G, page) score tile hits the MXU as one matmul.
 
 Speculative verify generalizes the query tile from one position to
 ``Q = spec_k + 1``: the tile becomes the row-flattened (Q·G, page)
@@ -50,7 +52,7 @@ NEG_INF = -1e30
 
 def _paged_kernel(tab_ref, len_ref, w_ref, q_ref, k_ref, v_ref, *rest,
                   page: int, n_pages: int, q_len: int, group: int,
-                  scale: float, quantized: bool):
+                  n_kv: int, scale: float, quantized: bool):
     if quantized:
         # int8 pools ride with per-(page, kv-head) f32 scales; the scale
         # tile is gathered by the same table entry as its page.
@@ -59,7 +61,7 @@ def _paged_kernel(tab_ref, len_ref, w_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
     length = len_ref[b]              # valid keys for this sequence
     window = w_ref[0]                # <= 0 means global
     # the q_len queries sit at positions length - q_len .. length - 1;
@@ -81,38 +83,40 @@ def _paged_kernel(tab_ref, len_ref, w_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                                   # (Q·G, Dh)
-        k = k_ref[0, :, 0, :]                             # (page, Dh)
-        v = v_ref[0, :, 0, :]
-        if quantized:
-            # dequantize the page tile in VMEM: int8 payload times the
-            # page's per-kv-head scale, compute in f32 end to end
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * ks_ref[0, 0]
-            v = v.astype(jnp.float32) * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (Q·G, page)
-        r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        qpos = min_qpos + r // group                      # per-row query pos
-        kpos = i * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos <= qpos
-        mask &= (window <= 0) | ((qpos - kpos) < jnp.maximum(window, 1))
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        # one page tile holds every KV head; walk the heads statically
+        for h in range(n_kv):
+            q = q_ref[h]                                  # (Q·G, Dh)
+            k = k_ref[:, h, :]                            # (page, Dh)
+            v = v_ref[:, h, :]
+            if quantized:
+                # dequantize the page tile in VMEM: int8 payload times
+                # the page's per-kv-head scale, f32 end to end
+                q = q.astype(jnp.float32)
+                k = k.astype(jnp.float32) * ks_ref[:, h:h + 1]
+                v = v.astype(jnp.float32) * vs_ref[:, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (Q·G, page)
+            r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            qpos = min_qpos + r // group                  # per-row query pos
+            kpos = i * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos <= qpos
+            mask &= (window <= 0) | ((qpos - kpos) < jnp.maximum(window, 1))
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            p = jnp.where(mask, p, 0.0)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(i == n_pages - 1)
     def _finalize():
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -152,37 +156,36 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
           .reshape(b, kv, q_len * group, dh))
 
     kernel = functools.partial(_paged_kernel, page=page, n_pages=n_pages,
-                               q_len=q_len, group=group, scale=scale,
-                               quantized=quantized)
-    page_spec = pl.BlockSpec((1, page, 1, dh),
-                             lambda b_, h_, i, tab, lens, w:
-                             (jnp.maximum(tab[b_, i], 0), 0, h_, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, q_len * group, dh),
-                     lambda b_, h_, i, tab, lens, w: (b_, h_, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
+                               q_len=q_len, group=group, n_kv=kv,
+                               scale=scale, quantized=quantized)
+    # A page tile carries all KV heads: its last two dims are the pool's
+    # full (KV, Dh), which meets the TPU tiling rule for any head count
+    # without relayouting the pool.
+    page_spec = pl.BlockSpec((None, page, kv, dh),
+                             lambda b_, i, tab, lens, w:
+                             (jnp.maximum(tab[b_, i], 0), 0, 0, 0))
+    q_spec = pl.BlockSpec((None, kv, q_len * group, dh),
+                          lambda b_, i, tab, lens, w: (b_, 0, 0, 0))
+    in_specs = [q_spec, page_spec, page_spec]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        # scale tiles gather with the same table entry as their page
-        scale_spec = pl.BlockSpec((1, 1),
-                                  lambda b_, h_, i, tab, lens, w:
-                                  (jnp.maximum(tab[b_, i], 0), h_))
+        # scale tiles (1, KV) gather with the same table entry as their
+        # page
+        scale_spec = pl.BlockSpec((None, 1, kv),
+                                  lambda b_, i, tab, lens, w:
+                                  (jnp.maximum(tab[b_, i], 0), 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+        operands += [jnp.asarray(k_scale, jnp.float32)[:, None],
+                     jnp.asarray(v_scale, jnp.float32)[:, None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kv, n_pages),
+        grid=(b, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, q_len * group, dh),
-            lambda b_, h_, i, tab, lens, w: (b_, h_, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((q_len * group,), jnp.float32),
-            pltpu.VMEM((q_len * group,), jnp.float32),
-            pltpu.VMEM((q_len * group, dh), jnp.float32),
+            pltpu.VMEM((kv, q_len * group, 1), jnp.float32),
+            pltpu.VMEM((kv, q_len * group, 1), jnp.float32),
+            pltpu.VMEM((kv, q_len * group, dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -190,7 +193,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, q_len * group, dh), q.dtype),
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(lengths, jnp.int32),

@@ -11,7 +11,8 @@ VMEM/MXU): split the sequence into C-length chunks; inside a chunk the
 contribution of earlier in-chunk tokens is an attention-like (C × C)
 matmul with decay weights, and the carry-in state contributes through a
 (C × Dh) @ (Dh × Dh) matmul — both MXU-shaped.  The (Dh × Dh) f32 state
-lives in VMEM scratch across the (sequential) chunk grid axis.
+lives, transposed, in VMEM scratch across the (sequential) chunk grid
+axis; the in-chunk prefix sum of log-decays is a triangular matmul.
 
 Grid: (B·H, S/C) — chunk axis innermost/sequential.
 BlockSpecs: r/k/v/w tiles (1, C, Dh) in VMEM; y tile (1, C, Dh); the
@@ -41,33 +42,40 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     w = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)                 # (Dh,)
+    u = u_ref[...].astype(jnp.float32)               # (1, Dh)
 
     logw = jnp.log(jnp.clip(w, 1e-8, 1.0))
-    cum = jnp.cumsum(logw, axis=0)                   # (C, Dh)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum); HIGHEST keeps the f32 log-decays exact
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    cum = jax.lax.dot((ti >= si).astype(jnp.float32), logw,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)   # (C, Dh)
     decay_to_t = jnp.exp(cum - logw)                 # prod over [0, t-1]
 
-    state = state_scr[...]                           # (Dh, Dh)
+    # the carried state is kept transposed, S^T (Dh_v, Dh_k), so the
+    # per-k-channel decay scales its lanes
+    state_t = state_scr[...]
     # inter-chunk: y_t += (r_t ⊙ decay_to_t) @ S_in
     rd = r * decay_to_t
-    y = jax.lax.dot(rd, state, preferred_element_type=jnp.float32)
+    y = jax.lax.dot_general(rd, state_t, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
     # intra-chunk: strictly-lower-triangular attention-like term
     att = jax.lax.dot_general(rd, k * jnp.exp(-cum),
                               (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (C, C)
-    ti = jax.lax.broadcasted_iota(jnp.int32, att.shape, 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, att.shape, 1)
     att = jnp.where(ti > si, att, 0.0)
     y += jax.lax.dot(att, v, preferred_element_type=jnp.float32)
     # bonus diagonal term: y_t += (r_t · (u ⊙ k_t)) v_t
-    y += jnp.sum(r * u[None, :] * k, axis=-1, keepdims=True) * v
+    y += jnp.sum(r * u * k, axis=-1, keepdims=True) * v
     y_ref[0] = y.astype(y_ref.dtype)
 
     # state update: S_out = diag(prod w) S_in + Σ_s (prod_{τ>s} w_τ ⊙ k_s) v_s^T
-    total = jnp.exp(cum[-1])                         # (Dh,)
-    kdec = k * jnp.exp(cum[-1][None, :] - cum)       # (C, Dh)
-    state_scr[...] = total[:, None] * state + jax.lax.dot_general(
-        kdec, v, (((0,), (0,)), ((), ())),
+    last = cum[chunk - 1:chunk]                      # (1, Dh)
+    kdec = k * jnp.exp(last - cum)                   # (C, Dh)
+    state_scr[...] = jnp.exp(last) * state_t + jax.lax.dot_general(
+        v, kdec, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(ic == nc - 1)
@@ -89,7 +97,7 @@ def wkv6(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
 
     rf, kf, vf, wf = flat(r), flat(k), flat(v), flat(w)
-    uf = jnp.broadcast_to(u[None], (b, h, dh)).reshape(b * h, dh)
+    uf = jnp.broadcast_to(u[None], (b, h, dh)).reshape(b * h, 1, dh)
 
     kernel = functools.partial(_wkv6_kernel, chunk=chunk, nc=nc)
     y, s_last = pl.pallas_call(
@@ -100,7 +108,7 @@ def wkv6(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
             pl.BlockSpec((1, chunk, dh), lambda bh, ic: (bh, ic, 0)),
             pl.BlockSpec((1, chunk, dh), lambda bh, ic: (bh, ic, 0)),
             pl.BlockSpec((1, chunk, dh), lambda bh, ic: (bh, ic, 0)),
-            pl.BlockSpec((1, dh), lambda bh, ic: (bh, 0)),
+            pl.BlockSpec((None, 1, dh), lambda bh, ic: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, dh), lambda bh, ic: (bh, ic, 0)),
@@ -117,5 +125,5 @@ def wkv6(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
     )(rf, kf, vf, wf, uf)
 
     y = y.reshape(b, h, s, dh).transpose(0, 2, 1, 3)
-    s_last = s_last.reshape(b, h, dh, dh)
+    s_last = s_last.reshape(b, h, dh, dh).transpose(0, 1, 3, 2)
     return y, s_last

@@ -95,6 +95,12 @@ class ModelSpec:
 
     # ---- stage decomposition -------------------------------------------------
 
+    def with_depth(self, n_layers: int) -> "ModelSpec":
+        """The same widths cut to the first ``n_layers`` blocks."""
+        assert 1 <= n_layers <= self.n_layers, (n_layers, self.n_layers)
+        return dataclasses.replace(self, n_layers=n_layers,
+                                   blocks=self.blocks[:n_layers])
+
     def layers_per_stage(self, pp: int) -> int:
         assert self.n_layers % pp == 0, (
             f"{self.name}: pp={pp} must divide n_layers={self.n_layers}")

@@ -1,54 +1,24 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""Thin wrappers over the installed jax (0.9) APIs the codebase shares.
 
-Two shims the whole codebase routes through:
-
-  shard_map   jax >= 0.6 exports it at the top level and renamed the
-              replication-check kwarg ``check_rep`` -> ``check_vma``;
-              older releases have it under jax.experimental.shard_map
-              with ``check_rep``.  Callers always pass ``check_vma`` and
-              this wrapper translates when needed.
-  tpu_compiler_params
-              pallas renamed ``pltpu.TPUCompilerParams`` ->
-              ``pltpu.CompilerParams``.  Kernels build the params through
-              this helper instead of naming the class.
+  shard_map            ``jax.shard_map`` (callers pass ``check_vma``).
+  cost_analysis        ``compiled.cost_analysis()`` as a plain dict.
+  tpu_compiler_params  ``pltpu.CompilerParams`` for the Pallas kernels.
 """
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.6 moved shard_map to the top level
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-    """shard_map with ``check_vma``/``check_rep`` translated per version."""
-    if "check_vma" in kw and "check_vma" not in _SHARD_MAP_PARAMS:
-        kw.setdefault("check_rep", kw.pop("check_vma"))
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+from jax import shard_map
 
 
 def cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() as a dict on every jax version.
-
-    Older releases return a one-element list of per-device dicts; newer
-    ones return the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """compiled.cost_analysis() as a dict (empty when unavailable)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def tpu_compiler_params(**kw):
-    """pltpu.CompilerParams / TPUCompilerParams, whichever this jax has."""
+    """pltpu.CompilerParams for a pallas_call."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:  # pragma: no cover - depends on installed jax
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
+    return pltpu.CompilerParams(**kw)
+
+
+__all__ = ["shard_map", "cost_analysis", "tpu_compiler_params"]

@@ -59,6 +59,9 @@ class TrainDriver:
             donate_argnums=0)
         self.metrics_log: List[Dict[str, float]] = []
         self.stage_times: List[float] = []
+        # the fault behind every restore-and-replay this driver has done
+        # — a run that must not hide faults checks ``restarts``
+        self.faults: List[str] = []
 
     # ---------------- main loop -------------------------------------------
 
@@ -91,12 +94,17 @@ class TrainDriver:
                     # failure budget, so max_restarts bounds *consecutive*
                     # failures, not sporadic ones over a long run
                     restarts = 0
-            except Exception:
+            except Exception as e:
                 restarts += 1
                 if restarts > self.cfg.max_restarts:
                     raise
+                self.faults.append(f"step {step}: {type(e).__name__}: {e}")
                 state, step = self.restore_latest(state)
         return state, step
+
+    @property
+    def restarts(self) -> int:
+        return len(self.faults)
 
     def restore_latest(self, state_template):
         rnd = self.ckpt.latest_complete_round()

@@ -25,3 +25,13 @@ except ImportError:  # pragma: no cover
 def rng():
     import numpy as np
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session", autouse=True)
+def pallas_interpret():
+    """The suite runs on the CPU, so it runs Pallas kernels in interpret
+    mode — an explicit choice, never inferred from the backend."""
+    from repro.kernels import ops
+    old = ops.set_interpret(True)
+    yield
+    ops.set_interpret(old)

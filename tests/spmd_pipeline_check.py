@@ -37,6 +37,11 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 
+# relative L2 gap allowed between each weight's update and the
+# reference's; fp32 reduction-order differences give ~1e-5
+UPDATE_RTOL = 1e-3
+
+
 def build_tiny_spec(arch: str):
     from repro.models import spec as S
     if arch == "dense":
@@ -148,6 +153,7 @@ def main(data, pp, tp, mode, arch, zero1=False, schedule="auto", vstages=1,
     ref_state = jax.tree.map(jnp.asarray, ref_state)
     if perm is not None:
         ref_state = _unpermute(ref_state, perm)
+    init_params = ref_state["params"]
 
     for i in range(steps):
         new_state, metrics = step(state, batch_dev)
@@ -181,6 +187,22 @@ def main(data, pp, tp, mode, arch, zero1=False, schedule="auto", vstages=1,
         np.testing.assert_allclose(
             np.asarray(g, np.float32), np.asarray(w, np.float32),
             atol=atol, rtol=2e-3, err_msg=f"param mismatch at {name}")
+    # Updates are small next to the weights, so the bound above cannot
+    # see a gradient off by a constant factor: each weight's total update
+    # (final - initial) must also match the reference's in relative L2.
+    # Not for MoE over data replicas: each replica routes, drops past
+    # capacity and balances load over its own tokens, the reference over
+    # the whole batch.
+    per_replica_routing = data > 1 and spec.moe is not None
+    for (path, g), w, w0 in zip(paths, flat_w,
+                                jax.tree.leaves(init_params)):
+        g, w, w0 = (np.asarray(a, np.float64) for a in (g, w, w0))
+        moved = np.linalg.norm(w - w0)
+        if moved and not per_replica_routing:
+            gap = np.linalg.norm(g - w) / moved
+            assert gap < UPDATE_RTOL, (
+                f"update mismatch at {jax.tree_util.keystr(path)}: "
+                f"relative gap {gap}")
     print(f"MATCH data={data} pp={pp} tp={tp} mode={mode} arch={arch} "
           f"zero1={zero1} schedule={schedule} v={vstages} steps={steps}")
 

@@ -115,6 +115,30 @@ def test_driver_gives_up_after_max_restarts(tmp_path):
         driver.run(state, 4)
 
 
+def test_driver_reports_restart_count(tmp_path):
+    """An injected fault is survived (restore + replay) and counted, so
+    a caller that must not hide faults can see it; a clean run counts 0."""
+    fired = []
+
+    def hook(step):
+        if step == 1 and not fired:
+            fired.append(step)
+            raise RuntimeError("simulated node failure")
+
+    bundle, driver, state = _driver_setup(tmp_path / "faulty",
+                                          failure_hook=hook)
+    _, step = driver.run(state, 3)
+    # no checkpoint yet: round 0 is replayed from scratch, identically
+    assert step == 3 and len(driver.metrics_log) == 4
+    assert driver.metrics_log[0] == driver.metrics_log[1]
+    assert driver.restarts == 1
+    assert driver.faults == ["step 1: RuntimeError: simulated node failure"]
+
+    bundle, clean, state = _driver_setup(tmp_path / "clean")
+    clean.run(state, 2)
+    assert clean.restarts == 0 and clean.faults == []
+
+
 def test_truncated_manifest_is_skipped(tmp_path):
     """A torn MANIFEST.json (crash mid-write on a pre-atomic layout, or
     a disk fault) must read as 'round incomplete', not crash the restart

@@ -25,6 +25,7 @@ FAST_MATRIX = [
     # per-chunk version rings, per-microbatch updates (vs the native
     # async sequential oracle, storage order)
     (1, 2, 1, "stash", "dense", 0, "interleaved_async", 2, 1),
+    (1, 2, 2, "stash", "dense", 0, "auto", 1, 2),   # TP within a stage
 ]
 
 SLOW_MATRIX = [
