@@ -64,6 +64,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs as obs_lib
 from repro.core import schedule as sched_lib
 from repro.core.schedule import (B_CHUNK, B_FROM_HEAD, B_MB, B_RESID_READ,
                                  B_VERSION, F_CHUNK, F_FROM_EMBEDS, F_MB,
@@ -107,8 +108,7 @@ class PipelineBundle:
     seq_len: int
     microbatch_size: int
     # observability hook (repro.obs.Observability or None = off): the
-    # driver reports one on_round("train", sched, ...) per executed
-    # round against this bundle's schedule table
+    # driver counts each executed round into its registry
     obs: Any = None
     # the optimizer train_step applies (an oracle replays the same one)
     optimizer: Any = None
@@ -220,6 +220,7 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
                                              keepdims=False))
 
     # ======================= F phase (shard_map body) ===================
+    @obs_lib.scoped(obs_lib.FWD)
     def f_phase(tick, weights, stash, resid, recv_f, embeds, windows,
                 thetas, enc_ring):
         row = gather_row(FT, tick)
@@ -251,13 +252,18 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
         resid = jax.lax.dynamic_update_index_in_dim(
             resid, jnp.where(valid, x_in[None].astype(resid.dtype), old),
             slot, 0)
-        h_send = jax.lax.ppermute(h, AXIS_STAGE, fwd_perm) if S > 1 else h
+        with jax.named_scope(obs_lib.SEND):
+            h_send = (jax.lax.ppermute(h, AXIS_STAGE, fwd_perm) if S > 1
+                      else h)
         aux = aux * valid.astype(aux.dtype)
         return stash, resid, h_send[None], h[None], aux[None]
 
     # ======================= B phase (shard_map body) ===================
-    def b_phase(tick, step, weights, stash, opt_state, resid, recv_b,
-                g_exit, grad_acc, windows, thetas, enc_ring, denc_ring):
+    # the stage's recompute, backward and accumulation are ``bwd``; a
+    # per-microbatch update is ``optimizer``, outside it
+    @obs_lib.scoped(obs_lib.BWD)
+    def b_grads(tick, weights, stash, resid, recv_b, g_exit, grad_acc,
+                windows, thetas, enc_ring, denc_ring):
         row = gather_row(BT, tick)
         b = row[B_MB]
         valid = b >= 0
@@ -313,31 +319,44 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
                 grad_acc = tree_add(grad_acc, dW)
             else:
                 grad_acc = tree_chunk_add(grad_acc, dW, row[B_CHUNK])
+        return row, valid, w_loc, dW, dx, dx_embeds, grad_acc, denc_ring
+
+    @obs_lib.scoped(obs_lib.OPTIMIZER)
+    def b_update(row, step, valid, weights, w_loc, opt_state, dW):
+        """Per-microbatch update of exactly the chunk this B row names:
+        vs == 1 updates the whole stage block in place; vs > 1 (async
+        interleaved) reads the chunk's weight and optimizer rows,
+        updates them, and writes them back — the stage's other chunks
+        are untouched this tick."""
+        upd_o = (tree_chunk(opt_state, row[B_CHUNK]) if vs > 1
+                 else opt_state)
+        upd_w = w_loc if vs > 1 else weights
+        if zero1_manual:
+            upd_w, upd_o = zero1_microbatch_update(
+                optimizer, dW, upd_o, upd_w, step, valid,
+                z1_axes=z1_axes, daxes=daxes, dnames=dnames, dp=dp)
+        else:
+            upd_w, upd_o = replicated_microbatch_update(
+                optimizer, dW, upd_o, upd_w, step, valid,
+                dnames=dnames)
+        if vs > 1:
+            return (tree_chunk_write(weights, row[B_CHUNK], upd_w),
+                    tree_chunk_write(opt_state, row[B_CHUNK], upd_o))
+        return upd_w, upd_o
+
+    def b_phase(tick, step, weights, stash, opt_state, resid, recv_b,
+                g_exit, grad_acc, windows, thetas, enc_ring, denc_ring):
+        row, valid, w_loc, dW, dx, dx_embeds, grad_acc, denc_ring = b_grads(
+            tick, weights, stash, resid, recv_b, g_exit, grad_acc,
+            windows, thetas, enc_ring, denc_ring)
+        if accumulate:
             new_w, new_opt = weights, opt_state
         else:
-            # per-microbatch update of exactly the chunk this B row
-            # names: vs == 1 updates the whole stage block in place;
-            # vs > 1 (async interleaved) reads the chunk's weight and
-            # optimizer rows, updates them, and writes them back — the
-            # stage's other chunks are untouched this tick.
-            upd_o = (tree_chunk(opt_state, row[B_CHUNK]) if vs > 1
-                     else opt_state)
-            upd_w = w_loc if vs > 1 else weights
-            if zero1_manual:
-                upd_w, upd_o = zero1_microbatch_update(
-                    optimizer, dW, upd_o, upd_w, step, valid,
-                    z1_axes=z1_axes, daxes=daxes, dnames=dnames, dp=dp)
-            else:
-                upd_w, upd_o = replicated_microbatch_update(
-                    optimizer, dW, upd_o, upd_w, step, valid,
-                    dnames=dnames)
-            if vs > 1:
-                new_w = tree_chunk_write(weights, row[B_CHUNK], upd_w)
-                new_opt = tree_chunk_write(opt_state, row[B_CHUNK], upd_o)
-            else:
-                new_w, new_opt = upd_w, upd_o
-
-        g_send = jax.lax.ppermute(dx, AXIS_STAGE, bwd_perm) if S > 1 else dx
+            new_w, new_opt = b_update(row, step, valid, weights, w_loc,
+                                      opt_state, dW)
+        with jax.named_scope(obs_lib.BWD), jax.named_scope(obs_lib.SEND):
+            g_send = (jax.lax.ppermute(dx, AXIS_STAGE, bwd_perm) if S > 1
+                      else dx)
         return (new_w, new_opt, g_send[None], grad_acc, dx_embeds[None],
                 denc_ring)
 
@@ -419,25 +438,27 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
         tokens, labels = batch["tokens"], batch["labels"]  # (R,Bmb,text)
         step = state["step"]
 
-        text_embeds = lm_head.embed_tokens(params["embed"], tokens)
-        if is_vlm:
-            embeds = jnp.concatenate(
-                [batch["patches"].astype(text_embeds.dtype), text_embeds],
-                axis=2)
-            lab_full = jnp.concatenate(
-                [jnp.full((R, bmb, n_patch), -1, labels.dtype), labels],
-                axis=2)
-        else:
-            embeds, lab_full = text_embeds, labels
-        embeds = jax.lax.with_sharding_constraint(
-            embeds.astype(compute_dtype), NamedSharding(mesh, emb_pspec))
+        with jax.named_scope(obs_lib.EMBED):
+            text_embeds = lm_head.embed_tokens(params["embed"], tokens)
+            if is_vlm:
+                embeds = jnp.concatenate(
+                    [batch["patches"].astype(text_embeds.dtype),
+                     text_embeds], axis=2)
+                lab_full = jnp.concatenate(
+                    [jnp.full((R, bmb, n_patch), -1, labels.dtype),
+                     labels], axis=2)
+            else:
+                embeds, lab_full = text_embeds, labels
+            embeds = jax.lax.with_sharding_constraint(
+                embeds.astype(compute_dtype), NamedSharding(mesh, emb_pspec))
 
         enc_vjp = None
         if has_enc:
             fr = batch["frames"].reshape(R * bmb, enc_len, d_enc)
-            enc_out_flat, enc_vjp = jax.vjp(
-                lambda ep, fx: encoder_fwd(ep, fx, spec),
-                params["encoder"], fr.astype(compute_dtype))
+            with jax.named_scope(obs_lib.FWD):
+                enc_out_flat, enc_vjp = jax.vjp(
+                    lambda ep, fx: encoder_fwd(ep, fx, spec),
+                    params["encoder"], fr.astype(compute_dtype))
             enc_ring = jax.lax.with_sharding_constraint(
                 enc_out_flat.reshape(R, bmb, enc_len, d_enc),
                 NamedSharding(mesh, enc_pspec))
@@ -478,20 +499,17 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
 
         win, th = params["layer_windows"], params["layer_thetas"]
 
-        def tick_body(carry, tick):
-            stash, resid, recv_f, h_all, aux = f_sharded(
-                tick, carry["w"], carry["stash"], carry["resid"],
-                carry["recv_f"], embeds, win, th, enc_ring)
-            carry["stash"], carry["resid"], carry["recv_f"] = \
-                stash, resid, recv_f
-            carry["aux_sum"] = carry["aux_sum"] + aux.sum()
-
-            # ---- head + loss for the exiting microbatch ----------------
+        # ---- head + loss for the exiting microbatch ----------------------
+        @obs_lib.scoped(obs_lib.HEAD)
+        def head_tick(carry, tick, h_all):
+            """The exit microbatch's loss and d(loss)/d(hidden); updates
+            ``carry``'s head, final norm and sums in place."""
             m_exit = jax.lax.dynamic_index_in_dim(
                 jnp.asarray(EXIT_T), tick, 0, keepdims=False)
             valid_e = m_exit >= 0
             msafe = jnp.clip(m_exit, 0, R - 1)
-            h_exit = h_all[S - 1]
+            with jax.named_scope(obs_lib.SEND):    # last stage -> head
+                h_exit = h_all[S - 1]
             lab = jax.lax.dynamic_index_in_dim(lab_full, msafe, 0,
                                                keepdims=False)
             vmask = (lab >= 0).astype(jnp.float32)
@@ -527,6 +545,16 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
                                              carry["fnorm"])
                 carry["head_opt"] = tree_select(valid_e, hf_opt,
                                                 carry["head_opt"])
+            return g_exit
+
+        def tick_body(carry, tick):
+            stash, resid, recv_f, h_all, aux = f_sharded(
+                tick, carry["w"], carry["stash"], carry["resid"],
+                carry["recv_f"], embeds, win, th, enc_ring)
+            carry["stash"], carry["resid"], carry["recv_f"] = \
+                stash, resid, recv_f
+            carry["aux_sum"] = carry["aux_sum"] + aux.sum()
+            g_exit = head_tick(carry, tick, h_all)
 
             # ---- backward phase -----------------------------------------
             new_w, new_opt, recv_b, gacc, dx_all, denc = b_sharded(
@@ -538,15 +566,16 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
 
             # stage 0's dx is d(embeddings) when its backward finishes a
             # microbatch's first chunk (schedule demb table)
-            b0 = jax.lax.dynamic_index_in_dim(
-                jnp.asarray(DEMB_T), tick, 0, keepdims=False)
-            valid_b0 = b0 >= 0
-            b0safe = jnp.clip(b0, 0, R - 1)
-            prev = jax.lax.dynamic_index_in_dim(carry["d_embeds"], b0safe, 0,
-                                                keepdims=False)
-            upd = jnp.where(valid_b0, dx_all[0], prev)
-            carry["d_embeds"] = jax.lax.dynamic_update_index_in_dim(
-                carry["d_embeds"], upd, b0safe, 0)
+            with jax.named_scope(obs_lib.EMBED):
+                b0 = jax.lax.dynamic_index_in_dim(
+                    jnp.asarray(DEMB_T), tick, 0, keepdims=False)
+                valid_b0 = b0 >= 0
+                b0safe = jnp.clip(b0, 0, R - 1)
+                prev = jax.lax.dynamic_index_in_dim(
+                    carry["d_embeds"], b0safe, 0, keepdims=False)
+                upd = jnp.where(valid_b0, dx_all[0], prev)
+                carry["d_embeds"] = jax.lax.dynamic_update_index_in_dim(
+                    carry["d_embeds"], upd, b0safe, 0)
             return carry, None
 
         carry, _ = jax.lax.scan(tick_body, carry,
@@ -558,37 +587,43 @@ def build_pipeline(spec: spec_lib.ModelSpec, plan: ParallelismPlan,
         step = state["step"]
 
         if accumulate:
-            g_st = jax.tree.map(lambda a: jnp.sum(a, axis=0) / R,
-                                carry["gacc"])
-            carry["w"], carry["opt"] = optimizer.update(
-                g_st, carry["opt"], carry["w"], step)
-            hf_new, hf_opt = optimizer.update(
-                {"h": carry["dhead_acc"] / R,
-                 "f": jax.tree.map(lambda a: a / R, carry["dfnorm_acc"])},
-                carry["head_opt"],
-                {"h": carry["head"], "f": carry["fnorm"]}, step)
-            carry["head"], carry["fnorm"] = hf_new["h"], hf_new["f"]
-            carry["head_opt"] = hf_opt
+            with jax.named_scope(obs_lib.OPTIMIZER):
+                g_st = jax.tree.map(lambda a: jnp.sum(a, axis=0) / R,
+                                    carry["gacc"])
+                carry["w"], carry["opt"] = optimizer.update(
+                    g_st, carry["opt"], carry["w"], step)
+                hf_new, hf_opt = optimizer.update(
+                    {"h": carry["dhead_acc"] / R,
+                     "f": jax.tree.map(lambda a: a / R,
+                                       carry["dfnorm_acc"])},
+                    carry["head_opt"],
+                    {"h": carry["head"], "f": carry["fnorm"]}, step)
+                carry["head"], carry["fnorm"] = hf_new["h"], hf_new["f"]
+                carry["head_opt"] = hf_opt
 
         # embedding update, once per round (DESIGN.md §7)
-        demb = carry["d_embeds"][:, :, n_patch:, :] if is_vlm \
-            else carry["d_embeds"]
-        d_table = lm_head.embed_bwd(params["embed"], tokens,
-                                    demb.astype(jnp.float32)) / R
-        emb2, eopt2 = optimizer.update(d_table, state["opt_embed"],
-                                       params["embed"], step)
+        with jax.named_scope(obs_lib.EMBED):
+            demb = carry["d_embeds"][:, :, n_patch:, :] if is_vlm \
+                else carry["d_embeds"]
+            d_table = lm_head.embed_bwd(params["embed"], tokens,
+                                        demb.astype(jnp.float32)) / R
+            emb2, eopt2 = optimizer.update(d_table, state["opt_embed"],
+                                           params["embed"], step)
         new_params["embed"] = emb2
         new_state["opt_embed"] = eopt2
 
         if has_enc:
-            denc_sum = jnp.sum(carry["denc"].astype(jnp.float32), axis=0)
-            (denc_params, _) = enc_vjp(
-                denc_sum.reshape(R * bmb, enc_len, d_enc).astype(
-                    compute_dtype))
-            encp2, encopt2 = optimizer.update(
-                jax.tree.map(lambda a: a.astype(jnp.float32) / R,
-                             denc_params),
-                state["opt_encoder"], params["encoder"], step)
+            with jax.named_scope(obs_lib.BWD):
+                denc_sum = jnp.sum(carry["denc"].astype(jnp.float32),
+                                   axis=0)
+                (denc_params, _) = enc_vjp(
+                    denc_sum.reshape(R * bmb, enc_len, d_enc).astype(
+                        compute_dtype))
+            with jax.named_scope(obs_lib.OPTIMIZER):
+                encp2, encopt2 = optimizer.update(
+                    jax.tree.map(lambda a: a.astype(jnp.float32) / R,
+                                 denc_params),
+                    state["opt_encoder"], params["encoder"], step)
             new_params["encoder"] = encp2
             new_state["opt_encoder"] = encopt2
 

@@ -20,6 +20,8 @@ from typing import Dict, Iterator, Optional
 import jax
 import numpy as np
 
+from repro import obs
+
 
 class SyntheticLM:
     """Zipf-ish token stream with EOS-delimited documents."""
@@ -60,19 +62,19 @@ class ShardedLoader:
     def get(self, step: int):
         t = self.batch_specs["tokens"]
         r, bmb, s = t.shape
-        host = self.source.round_batch(step, r, bmb)
+        with obs.span(obs.LOADER_DRAW):
+            host = dict(self.source.round_batch(step, r, bmb))
+            for k, spec in self.batch_specs.items():
+                if k not in host:
+                    host[k] = self.extra_fn(step, {k: spec})[k]
         out = {}
-        for k, spec in self.batch_specs.items():
-            if k in host:
-                data = host[k]
-            else:
-                data = self.extra_fn(step, {k: spec})[k]
+        with obs.span(obs.LOADER_PLACE):
+            for k, spec in self.batch_specs.items():
+                def cb(index, _data=host[k]):
+                    return _data[index]
 
-            def cb(index, _data=data):
-                return _data[index]
-
-            out[k] = jax.make_array_from_callback(spec.shape, spec.sharding,
-                                                  cb)
+                out[k] = jax.make_array_from_callback(spec.shape,
+                                                      spec.sharding, cb)
         return out
 
 

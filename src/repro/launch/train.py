@@ -58,11 +58,16 @@ def enable_compile_cache() -> str:
     """Turn the persistent compile cache on; entry points only.
 
     When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
-    no other directory is set here.
+    no other directory is set here.  The cache is keyed on the op
+    metadata too: by default JAX strips it from the key, so a step
+    loaded from the cache carries the ``op_name``s of whichever build
+    compiled it first, and a profile would name another program's work
+    (repro.obs scopes).
     """
     path = compile_cache_dir()
     if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 
@@ -108,9 +113,11 @@ def parser() -> argparse.ArgumentParser:
     ap.add_argument("--host-devices", type=int, default=None)
     ap.add_argument("--log", type=str, default=None)
     ap.add_argument("--trace-out", type=str, default=None,
-                    help="write a Chrome trace-event JSON of every "
-                         "training round (one track per stage; open in "
-                         "Perfetto / chrome://tracing)")
+                    help="record a jax.profiler trace of the steps after "
+                         "the first into this directory (.xplane.pb and "
+                         "perfetto_trace.json.gz, with the step's device "
+                         "scopes and the driver's host spans; open in "
+                         "Perfetto or TensorBoard)")
     ap.add_argument("--metrics-out", type=str, default=None,
                     help="write the metrics-registry snapshot JSON "
                          "(schema-checked by scripts/bench_check.py)")
@@ -204,7 +211,7 @@ def describe(spec, bundle) -> str:
 def main(argv=None):
     args = parser().parse_args(argv)
     enable_compile_cache()
-    obs = Observability(trace=bool(args.trace_out))
+    obs = Observability()
     spec, bundle = build(args, obs=obs)
     print(describe(spec, bundle))
     with contextlib.ExitStack() as stack:
@@ -214,7 +221,12 @@ def main(argv=None):
                              DriverConfig(checkpoint_every=args.ckpt_every))
         state = init_state(bundle)
         with obs.timer("launch_phase_seconds", phase="run") as t:
-            state, step = driver.run(state, args.steps)
+            # the first step compiles: the trace holds the steady steps
+            state, step = driver.run(state, min(args.steps, 1))
+            with (jax.profiler.trace(args.trace_out,
+                                     create_perfetto_trace=True)
+                  if args.trace_out else contextlib.nullcontext()):
+                state, step = driver.run(state, args.steps, step)
     dt = t.elapsed
     losses = [m["loss"] for m in driver.metrics_log]
     print(f"arch={spec.name} steps={step} time={dt:.1f}s "
@@ -222,11 +234,11 @@ def main(argv=None):
           f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
     for fault in driver.faults:
         print(f"  recovered from fault at {fault}")
-    print(" ", reconcile(bundle.sched, trace=obs.trace,
-                         registry=obs.registry, kind="train"))
-    obs.save(trace_out=args.trace_out, metrics_out=args.metrics_out)
+    print(" ", reconcile(bundle.sched, registry=obs.registry,
+                         kind="train"))
+    obs.save(metrics_out=args.metrics_out)
     if args.trace_out:
-        print(f"wrote pipeline trace to {args.trace_out}")
+        print(f"wrote profiler trace to {args.trace_out}")
     if args.metrics_out:
         print(f"wrote metrics snapshot to {args.metrics_out}")
     if args.log:

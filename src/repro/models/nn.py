@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops as kernel_ops
 from repro.parallel.mesh import maybe_axis_index, maybe_psum
 from repro.quant import maybe_dequant, quantize_kv_page_batched
@@ -142,6 +143,7 @@ def _attn_mask(q_pos, k_pos, window, causal: bool):
     return m
 
 
+@obs.scoped(obs.ATTENTION)
 def _sdpa_naive(q, k, v, mask):
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -150,6 +152,7 @@ def _sdpa_naive(q, k, v, mask):
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
+@obs.scoped(obs.ATTENTION)
 def _sdpa_flash_jnp(q, k, v, q_pos, k_pos, window, causal, block: int = _FLASH_BLOCK):
     """Blockwise (flash) attention in pure jnp: O(S*block) memory.
 
@@ -200,6 +203,7 @@ def _sdpa_flash_jnp(q, k, v, q_pos, k_pos, window, causal, block: int = _FLASH_B
     return out.swapaxes(1, 2).astype(q.dtype)  # (B, S, H, Dh)
 
 
+@obs.scoped(obs.ATTENTION)
 def _sdpa_decode_seq_sharded(q, k, v, q_pos, k_pos, window, seq_axis):
     """Decode attention over a sequence-sharded KV cache (SP decode).
 
@@ -494,8 +498,9 @@ def attention(
             and causal and kernel_ops.use_pallas()):
         # Pallas TPU flash kernel (kernels/flash_attention.py): GQA mapped
         # in the BlockSpec index map, window rides in SMEM.
-        out = kernel_ops.flash_attention(q, k, v, causal=True,
-                                         window=window)
+        with jax.named_scope(obs.ATTENTION):
+            out = kernel_ops.flash_attention(q, k, v, causal=True,
+                                             window=window)
         out = out.reshape(b, s, st.n_heads_local * st.d_head)
         out = jnp.einsum("bsk,kd->bsd", out, wo)
         return maybe_psum(out, tp_axis), None
@@ -751,6 +756,7 @@ def _token_shift(x, prev=None):
     return jnp.concatenate([prev[:, None], x], axis=1)[:, : x.shape[1]]
 
 
+@obs.scoped(obs.WKV)
 def wkv6_chunked(r, k, v, w, u, *, chunk: int, s0=None):
     """RWKV6 WKV with matrix-valued state and per-channel decay.
 
@@ -836,8 +842,10 @@ def rwkv_time_mix(p, x, rst: RWKVStatic, tp_axis: Optional[str], state=None):
 
     if s0 is None and state is None and kernel_ops.use_pallas():
         # Pallas TPU chunked WKV kernel (kernels/wkv6.py), train mode.
-        y, s_last = kernel_ops.wkv6(r, k, v, w.astype(r.dtype),
-                                    p["u"].reshape(h, dh), chunk=rst.chunk)
+        with jax.named_scope(obs.WKV):
+            y, s_last = kernel_ops.wkv6(r, k, v, w.astype(r.dtype),
+                                        p["u"].reshape(h, dh),
+                                        chunk=rst.chunk)
     else:
         y, s_last = wkv6_chunked(r, k, v, w.astype(r.dtype),
                                  p["u"].reshape(h, dh), chunk=rst.chunk,

@@ -21,6 +21,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import obs as obs_lib
 from repro.checkpoint.manager import CheckpointManager, reshard_stages
 from repro.core import profiler as prof
 from repro.core.partitioner import PlanChoice, plan_search
@@ -44,12 +45,15 @@ class TrainDriver:
         self.cfg = cfg
         self.ckpt = CheckpointManager(ckpt_dir)
         self.failure_hook = failure_hook or (lambda step: None)
-        # observability (repro.obs.Observability): one on_round("train")
-        # per executed round.  The SPMD step is one fused device program,
-        # so the host cannot time stages individually; stage_seconds_fn
-        # (step -> per-stage seconds, e.g. from a profiler hook or a
-        # straggler harness) feeds the stage_round_seconds{stage=}
-        # histograms that replan_from_registry re-plans from.
+        # observability (repro.obs.Observability): each executed round
+        # feeds round_seconds / rounds_total{kind=train}.  The SPMD step
+        # is one fused device program, so the host cannot time stages
+        # individually; stage_seconds_fn (step -> per-stage seconds,
+        # e.g. from a profiler hook or a straggler harness) feeds the
+        # stage_round_seconds{stage=} histograms that
+        # replan_from_registry re-plans from.  Where the round's time
+        # goes is named in the profiler's own trace: the host spans
+        # below (repro.obs.TRAIN_SPANS) and the step's device scopes.
         self.obs = obs if obs is not None else getattr(bundle, "obs", None)
         self.stage_seconds_fn = stage_seconds_fn
         self._jit_step = jax.jit(
@@ -70,26 +74,9 @@ class TrainDriver:
         restarts = 0
         while step < n_rounds:
             try:
-                self.failure_hook(step)          # may raise (simulated fault)
-                batch = self.loader.get(step)
-                clk = (self.obs.clock if self.obs is not None
-                       else time.perf_counter)
-                t0 = clk()
-                state, metrics = self._jit_step(state, batch)
-                jax.block_until_ready(metrics["loss"])
-                t1 = clk()
-                self.stage_times.append(t1 - t0)
-                if self.obs is not None:
-                    self.obs.on_round("train", self.bundle.sched, t0, t1)
-                    if self.stage_seconds_fn is not None:
-                        hist = self.obs.histogram("stage_round_seconds")
-                        for s, sec in enumerate(self.stage_seconds_fn(step)):
-                            hist.observe(float(sec), stage=s)
-                self.metrics_log.append(
-                    {k: float(v) for k, v in metrics.items()})
-                step += 1
-                if step % self.cfg.checkpoint_every == 0:
-                    self.ckpt.save(step, state, self.bundle.plan.pp)
+                with obs_lib.span(obs_lib.TRAIN_ROUND):
+                    state, step, saved = self._round(state, step)
+                if saved:
                     # durable progress: a complete checkpoint resets the
                     # failure budget, so max_restarts bounds *consecutive*
                     # failures, not sporadic ones over a long run
@@ -101,6 +88,36 @@ class TrainDriver:
                 self.faults.append(f"step {step}: {type(e).__name__}: {e}")
                 state, step = self.restore_latest(state)
         return state, step
+
+    def _round(self, state, step: int):
+        """One round under its host spans; returns (state, step + 1,
+        whether a checkpoint was saved)."""
+        self.failure_hook(step)          # may raise (simulated fault)
+        with obs_lib.span(obs_lib.TRAIN_LOAD):
+            batch = self.loader.get(step)
+        clk = self.obs.clock if self.obs is not None else time.perf_counter
+        t0 = clk()
+        with obs_lib.span(obs_lib.TRAIN_DISPATCH):
+            state, metrics = self._jit_step(state, batch)
+        with obs_lib.span(obs_lib.TRAIN_WAIT):
+            jax.block_until_ready(metrics["loss"])
+        t1 = clk()
+        self.stage_times.append(t1 - t0)
+        if self.obs is not None:
+            self.obs.count_round("train", t0, t1)
+            if self.stage_seconds_fn is not None:
+                hist = self.obs.histogram("stage_round_seconds")
+                for s, sec in enumerate(self.stage_seconds_fn(step)):
+                    hist.observe(float(sec), stage=s)
+        with obs_lib.span(obs_lib.TRAIN_READBACK):
+            self.metrics_log.append(
+                {k: float(v) for k, v in metrics.items()})
+        step += 1
+        if step % self.cfg.checkpoint_every:
+            return state, step, False
+        with obs_lib.span(obs_lib.TRAIN_CHECKPOINT):
+            self.ckpt.save(step, state, self.bundle.plan.pp)
+        return state, step, True
 
     @property
     def restarts(self) -> int:

@@ -85,17 +85,23 @@ def test_compile_cache_dir_follows_env_else_checkout():
 
 def test_enable_compile_cache_sets_only_the_checkout_path(monkeypatch):
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cc")
-    assert train.enable_compile_cache() == "/elsewhere/cc"
-    assert jax.config.jax_compilation_cache_dir == before
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     try:
+        assert train.enable_compile_cache() == "/elsewhere/cc"
+        assert jax.config.jax_compilation_cache_dir == before
+        # a cached step keeps the op_names (profiler scopes) it was
+        # compiled with, so they are part of the key
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert train.enable_compile_cache() == os.path.join(ROOT,
                                                             ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == os.path.join(
             ROOT, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          keyed)
 
 
 def test_kernel_off_tpu_needs_an_explicit_interpret_choice():

@@ -355,8 +355,11 @@ def test_train_driver_reports_rounds_and_stage_seconds(tmp_path):
     assert reg.histogram("round_seconds").stats(kind="train")["count"] == 3
     assert reg.histogram("stage_round_seconds").stats(stage=0)["count"] == 3
     assert stage_seconds(reg, 1) == [pytest.approx(0.01)]
-    recs = [r for r in obs.trace.rounds if r.kind == "train"]
-    assert len(recs) == 3 and all(r.n_spans > 0 for r in recs)
+    # a training round is traced by the profiler (host spans, device
+    # scopes), never synthesized from the table: the registry has it all
+    assert obs.trace.rounds == []
+    assert reg.histogram("round_seconds").stats(kind="train")["sum"] == \
+        pytest.approx(sum(driver.stage_times))
 
 
 # ---------------------------------------------------------------------------
