@@ -9,9 +9,18 @@ running the interpreter.  ``set_interpret(False)`` forces the Mosaic
 lowering, which is how kernels are compiled for a described (not
 attached) TPU.
 
-``use_pallas()`` gates the dispatch from models/nn.py: by default the
-XLA-lowerable jnp twins run; set REPRO_USE_PALLAS=1 (or call
-``enable(True)``) to route attention / WKV through the kernels.
+Dispatch from models/nn.py:
+
+  * training attention — ``use_flash()``: the flash kernel (forward and
+    backward) wherever a kernel can run, that is on a TPU or in the
+    caller's interpret mode; the jnp twin elsewhere.  Tile sizes follow
+    the call's shape (``flash_attention.choose_blocks``).
+  * WKV, mamba and paged decode — ``use_pallas()``: the XLA-lowerable jnp
+    twins by default; REPRO_USE_PALLAS=1 routes them through the
+    kernels.
+
+``enable(on)`` overrides both: ``enable(False)`` forces every twin,
+``enable(True)`` every kernel.
 """
 from __future__ import annotations
 
@@ -21,6 +30,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention import choose_blocks, fit_blocks
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.mamba_scan import mamba_scan as _mamba
 from repro.kernels.paged_attention import paged_attention as _paged
@@ -29,15 +39,25 @@ from repro.kernels.wkv6 import wkv6 as _wkv6
 _FORCE: Optional[bool] = None
 
 
-def enable(on: bool = True):
+def enable(on: Optional[bool] = True):
+    """Force every kernel on (True) or every twin (False); None restores
+    the dispatch rules."""
     global _FORCE
     _FORCE = on
 
 
 def use_pallas() -> bool:
+    """WKV, mamba and paged decode take their kernels."""
     if _FORCE is not None:
         return _FORCE
     return os.environ.get("REPRO_USE_PALLAS", "0") == "1"
+
+
+def use_flash() -> bool:
+    """Training attention takes the flash kernel."""
+    if _FORCE is not None:
+        return _FORCE
+    return bool(_INTERPRET) or jax.default_backend() == "tpu"
 
 
 _INTERPRET: Optional[bool] = None
@@ -63,25 +83,29 @@ def interpret_mode() -> bool:
     return False
 
 
-def flash_attention(q, k, v, *, causal: bool = True, window: int = -1,
-                    block_q: int = 128, block_k: int = 128):
+def flash_attention(q, k, v, *, causal: bool = True, window=-1,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None):
     """Shape-padding wrapper: pads Sq/Sk up to block multiples and crops.
 
-    Padding keys sit *after* the real ones, so causal masking plus the
-    in-kernel kpos bound keeps them unattended for any real query.
+    Tiles come from the shape (``choose_blocks``) unless ``block_q`` and
+    ``block_k`` name them.  Padding keys sit *after* the real ones, so
+    causal masking keeps them unattended for any real query; padding
+    queries are cropped, so their gradient is 0.
     """
     b, sq, h, dh = q.shape
     sk = k.shape[1]
-    block_q = min(block_q, max(sq, 8))
-    block_k = min(block_k, max(sk, 8))
+    if block_q is None:
+        block_q, block_k = choose_blocks(sq, sk)
+    else:
+        block_q, block_k = fit_blocks(block_q, block_k, sq, sk)
     pq = (-sq) % block_q
     pk = (-sk) % block_k
     qp = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0))) if pq else q
     kp = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0))) if pk else k
     vp = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0))) if pk else v
-    out = _flash(qp, kp, vp, causal=causal, window=window,
-                 block_q=block_q, block_k=block_k,
-                 interpret=interpret_mode())
+    out = _flash(qp, kp, vp, causal=causal, window=window, block_q=block_q,
+                 block_k=block_k, interpret=interpret_mode())
     return out[:, :sq]
 
 

@@ -23,10 +23,11 @@ from repro.kernels import ops as kernel_ops
 from repro.parallel.mesh import maybe_axis_index, maybe_psum
 from repro.quant import maybe_dequant, quantize_kv_page_batched
 
-# Sequence-length product above which attention switches to the blockwise
-# (flash-style) jnp implementation to keep activation memory O(S * block).
-# 4M ⇒ every ≥2k×2k attention goes blockwise (train_4k's 4k×4k included —
-# the naive path would materialize (mb, h, 4k, 4k) f32 score tensors).
+# Sequence-length product above which attention switches to flash
+# attention (the Pallas kernel where one can run, else the blockwise jnp
+# twin) to keep activation memory O(S * block).  4M ⇒ every ≥2k×2k
+# attention goes blockwise (train_4k's 4k×4k included — the naive path
+# would materialize (mb, h, 4k, 4k) f32 score tensors).
 _FLASH_THRESHOLD = 4 * 1024 * 1024
 _FLASH_BLOCK = 1024
 
@@ -158,7 +159,8 @@ def _sdpa_flash_jnp(q, k, v, q_pos, k_pos, window, causal, block: int = _FLASH_B
 
     Scans over KV blocks carrying running (max, sum, acc) — the TPU Pallas
     kernel in repro.kernels.flash_attention is the hardware version of this
-    loop; this is the XLA-lowerable twin used inside jit'd training graphs.
+    loop; this is its XLA-lowerable twin, which training graphs run where
+    no kernel can (``kernel_ops.use_flash()``).
     """
     b, sq, h, dh = q.shape
     sk = k.shape[1]
@@ -494,28 +496,26 @@ def attention(
         k_pos = k_positions_new
 
     causal = st.causal and cross_x is None
-    if (kv_cache is None and paged_kv is None and cross_x is None
-            and causal and kernel_ops.use_pallas()):
-        # Pallas TPU flash kernel (kernels/flash_attention.py): GQA mapped
-        # in the BlockSpec index map, window rides in SMEM.
+    long = s * k.shape[1] > _FLASH_THRESHOLD
+    if (long and causal and kv_cache is None and paged_kv is None
+            and kernel_ops.use_flash()):
+        # causal self-attention over a whole sequence (training: query
+        # and key i sit at position i): the Pallas flash kernel and its
+        # own backward, GQA in the BlockSpec index maps, window in SMEM
         with jax.named_scope(obs.ATTENTION):
             out = kernel_ops.flash_attention(q, k, v, causal=True,
                                              window=window)
-        out = out.reshape(b, s, st.n_heads_local * st.d_head)
-        out = jnp.einsum("bsk,kd->bsd", out, wo)
-        return maybe_psum(out, tp_axis), None
-
-    # GQA: broadcast kv heads to query heads
-    groups = st.n_heads_local // k.shape[2]
-    k = jnp.repeat(k, groups, axis=2)
-    v = jnp.repeat(v, groups, axis=2)
-
-    q_pos = positions[0] if positions.ndim == 2 else positions
-    if s * k.shape[1] <= _FLASH_THRESHOLD:
-        mask = _attn_mask(q_pos, k_pos, window, causal)
-        out = _sdpa_naive(q, k, v, mask[None, None])
     else:
-        out = _sdpa_flash_jnp(q, k, v, q_pos, k_pos, window, causal)
+        # GQA: broadcast kv heads to query heads
+        groups = st.n_heads_local // k.shape[2]
+        k = jnp.repeat(k, groups, axis=2)
+        v = jnp.repeat(v, groups, axis=2)
+        q_pos = positions[0] if positions.ndim == 2 else positions
+        if long:
+            out = _sdpa_flash_jnp(q, k, v, q_pos, k_pos, window, causal)
+        else:
+            mask = _attn_mask(q_pos, k_pos, window, causal)
+            out = _sdpa_naive(q, k, v, mask[None, None])
 
     out = out.reshape(b, s, st.n_heads_local * st.d_head)
     out = jnp.einsum("bsk,kd->bsd", out, wo)

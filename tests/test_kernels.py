@@ -1,8 +1,9 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode, CPU).
 
-Sweeps shapes, dtypes, GQA ratios, window sizes, block sizes; plus the
-model-level dispatch equivalence (use_pallas on/off must not change the
-transformer output).
+Sweeps shapes, dtypes, GQA ratios, window sizes, block sizes, for the
+flash kernel's gradients too; plus the model-level dispatch equivalence
+(kernels on/off must not change the transformer output, nor, through
+the flash kernel's backward, its gradients).
 """
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,33 @@ def test_flash_attention_matches_oracle(b, sq, sk, h, kv, dh, causal,
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=atol, rtol=1e-2)
+
+
+@pytest.mark.parametrize(
+    "b,sq,sk,h,kv,dh,causal,window,dt,bq,bk",
+    FLASH_CASES + [(1, 256, 256, 8, 2, 120, True, -1, jnp.bfloat16, 128,
+                    128)])                             # danube's d_head
+def test_flash_attention_gradients_match_oracle(b, sq, sk, h, kv, dh, causal,
+                                                window, dt, bq, bk):
+    """dQ, dK, dV of the kernel's own backward against autodiff of the
+    naive oracle."""
+    q, k, v = _qkv(b, sq, sk, h, kv, dh, dt, seed=sq * h + dh)
+    do = jax.random.normal(jax.random.key(dh), q.shape, dt)
+
+    def kernel(q, k, v):
+        return ops.flash_attention(q, k, v, causal=causal, window=window,
+                                   block_q=bq, block_k=bk)
+
+    def oracle(q, k, v):
+        return ref.attention_ref(q, k, v, causal=causal, window=window)
+
+    got = jax.vjp(kernel, q, k, v)[1](do)
+    want = jax.vjp(oracle, q, k, v)[1](do)
+    tol = 2e-2 if dt == jnp.bfloat16 else 1e-5
+    for name, g, w in zip(("dq", "dk", "dv"), got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        np.testing.assert_allclose(g, w, rtol=0, err_msg=name,
+                                   atol=tol * max(1.0, np.abs(w).max()))
 
 
 def test_flash_traced_window():
@@ -234,7 +262,7 @@ def test_paged_attention_garbage_pages_ignored():
 
 @pytest.mark.parametrize("arch", ["dense", "rwkv", "hybrid"])
 def test_model_dispatch_equivalence(arch):
-    """use_pallas() on/off must not change transformer outputs."""
+    """Kernels on/off must not change transformer outputs."""
     import sys as _sys
     import os as _os
     _sys.path.insert(0, _os.path.dirname(__file__))
@@ -255,6 +283,62 @@ def test_model_dispatch_equivalence(arch):
         ops.enable(True)
         y1, _ = full_transformer(params, x, st, positions=pos)
     finally:
-        ops.enable(False)
+        ops.enable(None)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                atol=2e-4, rtol=1e-3)
+
+
+def test_model_dispatch_equivalence_gradients():
+    """Past the flash threshold the training path's attention is the
+    kernel (interpret mode here): jax.grad through full_transformer agrees
+    with the jnp twin's, sliding-window layers included."""
+    import sys as _sys
+    import os as _os
+    _sys.path.insert(0, _os.path.dirname(__file__))
+    from spmd_pipeline_check import build_tiny_spec
+    from repro.models import nn
+    from repro.models.init import init_params
+    from repro.models.stage import full_transformer, make_statics
+    from repro.parallel.mesh import ParallelismPlan
+
+    s = 2304
+    assert s * s > nn._FLASH_THRESHOLD
+    spec = build_tiny_spec("dense")
+    plan = ParallelismPlan(pp=1, tp=1, microbatches=1, remat=False)
+    params, _ = init_params(spec, plan, jax.random.key(3), jnp.float32)
+    st = make_statics(spec, plan, tokens_per_mb=s)
+    x = jax.random.normal(jax.random.key(4), (1, s, spec.d_model))
+    dy = jax.random.normal(jax.random.key(5), (1, s, spec.d_model))
+    pos = jnp.arange(s)[None]
+
+    # differentiate the float leaves (the layer windows are int32)
+    leaves, treedef = jax.tree.flatten(params)
+    trained = [jnp.issubdtype(a.dtype, jnp.floating) for a in leaves]
+
+    def loss(weights, x):
+        it = iter(weights)
+        full = [next(it) if t else a for a, t in zip(leaves, trained)]
+        y, _ = full_transformer(jax.tree.unflatten(treedef, full), x, st,
+                                positions=pos)
+        return jnp.sum(y * dy)
+
+    def grad():    # traced anew, under the dispatch rule of the moment
+        return jax.jit(jax.grad(loss, argnums=(0, 1)))(weights, x)
+
+    weights = [a for a, t in zip(leaves, trained) if t]
+    try:
+        assert ops.use_flash()
+        g_kernel = grad()
+        ops.enable(False)
+        g_twin = grad()
+    finally:
+        ops.enable(None)
+    names = [jax.tree_util.keystr(p) for (p, _), t in zip(
+        jax.tree_util.tree_leaves_with_path(params), trained) if t] + ["x"]
+    flat_k, flat_t = jax.tree.leaves(g_kernel), jax.tree.leaves(g_twin)
+    assert len(flat_k) == len(flat_t) == len(names)
+    for name, a, b in zip(names, flat_k, flat_t):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=1e-4 * max(1.0, np.abs(b).max()),
+            err_msg=name)

@@ -61,16 +61,40 @@ def compile_tpu(one_chip):
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
-@pytest.mark.parametrize("h,kv,dh,window", [
+FLASH_WIDTHS = pytest.mark.parametrize("h,kv,dh,window", [
     (40, 8, 128, -1),      # qwen3-14b
     (32, 8, 120, 4096),    # h2o-danube3-4b (Dh not a multiple of 128)
-], ids=["qwen3-14b", "danube3-4b"])
+    (8, 4, 256, 1024),     # gemma3-4b's local layers (Dh 256)
+], ids=["qwen3-14b", "danube3-4b", "gemma3-4b"])
+
+
+@FLASH_WIDTHS
 def test_flash_attention_compiles_for_v5e(compile_tpu, h, kv, dh, window):
     s = 4096
     fn = functools.partial(ops.flash_attention, causal=True, window=window)
     c = compile_tpu(fn, ((1, s, h, dh), BF16), ((1, s, kv, dh), BF16),
                     ((1, s, kv, dh), BF16))
     assert "tpu_custom_call" in c.as_text()
+
+
+@FLASH_WIDTHS
+def test_flash_attention_backward_compiles_for_v5e(compile_tpu, h, kv, dh,
+                                                   window):
+    """The training path's vjp: the forward that saves the log-sum-exp and
+    both backward kernels, each under its own name."""
+    s = 4096
+
+    def fwd_bwd(q, k, v, do):
+        out, vjp = jax.vjp(functools.partial(
+            ops.flash_attention, causal=True, window=window), q, k, v)
+        return out, vjp(do)
+
+    c = compile_tpu(fwd_bwd, ((1, s, h, dh), BF16), ((1, s, kv, dh), BF16),
+                    ((1, s, kv, dh), BF16), ((1, s, h, dh), BF16))
+    calls = [line for line in c.as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert sum(f"%{name}" in line for line in calls) == 1, name
 
 
 @pytest.mark.parametrize("q_len,kv_dtype", [
