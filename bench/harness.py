@@ -7,6 +7,23 @@ through the ``configs`` entry's ``file``) and its traffic
 ``bench/metrics/<metric>.py``.  A cell is added by adding files and
 entries, never by editing this module.
 
+The architecture is found the same way: the configuration's "mixer"
+names ``bench/archs/<mixer>.py`` (its interface: bench/archs), which
+gives the reference its layers, the FLOP count its terms, the build
+check its widths and the trace its mixer's scopes.  An architecture is
+added by adding, and editing no file that is there:
+
+* ``bench/archs/<mixer>.py``;
+* ``bench/configs/<name>.json``, with ``launcher_args`` (passed to the
+  launcher after the cell's own arguments: a chip's share of the
+  experts, a slice of the vocabulary), ``aux_loss_weight`` (where a
+  block returns an auxiliary loss), ``reduced`` and ``assumed`` where
+  needed;
+* ``bench/traffic/<traffic>.json``, where the cell needs its own traffic;
+* ``bench/metrics/<scope>_ms.py`` for a scope of its own, reading
+  ``record["phases"]["scope_ms"]``;
+* its entries in BENCHMARK.json.
+
 The run builds the training step through the program's launcher
 (``repro.launch.train.build``) on the cell's chips, makes the state on
 the device from ``--seed`` with the benchmark's own weights, and drives
@@ -16,7 +33,11 @@ the program's ``ShardedLoader``.  Set-up ends after the first
 the readings that the plain reference (``bench/reference.py``) is
 compared with once the window has closed.
 The window then runs steps back to back, each dispatched when the last
-has returned, for ``--seconds``.
+has returned, for ``--seconds``.  With ``--trace 1`` a profiler traces
+the window; once it has closed, the trace and the HLO text of the step
+it ran give ``record["trace"]`` (bench/trace.py) and ``record["phases"]``
+(bench/phases.py): device ms a step by phase, under each of the mixer's
+scopes, and device idle ms under the program's host spans.
 """
 from __future__ import annotations
 
@@ -36,13 +57,11 @@ import time
 from typing import Callable, Optional
 from unittest import mock
 
+from bench import CellFailed, archs
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
 CHECK_STEPS = 3
-
-
-class CellFailed(Exception):
-    """The run cannot give a result; nothing is printed on stdout."""
 
 
 def load_json(*parts):
@@ -120,7 +139,8 @@ def launcher_argv(cell: Cell) -> list:
             "--schedule", plan["schedule"],
             "--microbatches", str(tr["microbatches"]),
             "--global-batch", str(rows),
-            "--seq-len", str(tr["seq_len"]), "--dtype", cfg["dtype"]]
+            "--seq-len", str(tr["seq_len"]), "--dtype", cfg["dtype"],
+            *cfg.get("launcher_args", ())]
 
 
 def check_build(cell: Cell, spec, bundle):
@@ -137,13 +157,9 @@ def check_build(cell: Cell, spec, bundle):
            "stash_mode": bundle.plan.stash_mode,
            "remat": bundle.plan.remat, "pp": bundle.plan.pp,
            "tp": bundle.plan.tp}
-    if cfg["mixer"] == "attn":
-        want.update(n_heads=cfg["num_attention_heads"],
-                    n_kv=cfg["num_key_value_heads"], d_head=cfg["head_dim"])
-        got.update(n_heads=spec.n_heads, n_kv=spec.n_kv, d_head=spec.d_head)
-    else:
-        want.update(d_head=cfg["head_size"])
-        got.update(d_head=spec.rwkv.head_dim)
+    mixer_want, mixer_got = archs.load(cfg).spec_check(cfg, spec)
+    want.update(mixer_want)
+    got.update(mixer_got)
     opt = bundle.optimizer
     for k in ("lr", "b1", "b2", "eps"):
         want[k], got[k] = cfg["optimizer"][k], getattr(opt, k)
@@ -360,15 +376,35 @@ class Program:
             "step_times": driver.stage_times[CHECK_STEPS:],
             "losses": [m["loss"] for m in driver.metrics_log],
             "compiles": len(self.compiles) - n_compiles,
-            "memory": stats, "trace": None}
+            "memory": stats, "trace": None, "phases": None}
         print(f"memory after the window: {stats}", file=sys.stderr)
         if trace_dir:
+            from bench import phases
             from bench import trace as trace_lib
             try:
-                record["trace"] = trace_lib.reduce(*trace_lib.load(trace_dir))
+                devices, host = trace_lib.load(trace_dir)
             finally:
                 shutil.rmtree(trace_dir, ignore_errors=True)
+            record["trace"] = trace_lib.reduce(devices, host)
+            named = phases.with_op_names(
+                devices, phases.hlo_op_names(self.step_hlo()))
+            r = phases.reduce(named, host, archs.load(self.cfg).SCOPES)
+            if record["steps"]:
+                print(phases.table(r, record["steps"]), file=sys.stderr)
+                record["phases"] = phases.per_step_ms(r, record["steps"])
         return record
+
+    def step_hlo(self) -> str:
+        """The HLO text of the step the window ran: the driver's own
+        jitted step, lowered for the state's and the batch's shapes, so
+        that its instruction names are those of the trace."""
+        jax, b = self.jax, self.bundle
+        state = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            jax.eval_shape(b.init_state, jax.random.key(0)),
+            b.state_shardings())
+        return self.driver._jit_step.lower(
+            state, b.batch_specs()).compile().as_text()
 
 
 def reference_readings(program: Program, *, fp8: bool = False,

@@ -1,15 +1,18 @@
-"""Device time by training phase and sequence mixer, and device idle time
-by the program's host spans, from a JAX profiler trace of the window.
+"""Device time by training phase and under the sequence mixer's scopes, and
+device idle time by the program's host spans, from a JAX profiler trace of
+the window.
 
 It reads the names the program gives its own work (``repro/obs``):
 
 * device scopes, which reach each HLO op's ``op_name`` metadata.  An
   op's phase is the first of PHASES on its ``op_name`` path, once the
   autodiff and batching wrappers (``jvp(``, ``transpose(``, ``vmap(``)
-  are stripped; an op under none is OTHER.  An op is in the mixer if
-  any of MIXERS is on its path, so the mixer cuts across ``fwd`` and
-  ``bwd``.  Time is each op's self time (bench/trace.py), so the
-  phases and OTHER add up to the device's busy time;
+  are stripped; an op under none is OTHER.  The scopes of a cell's
+  mixer are its architecture module's ``SCOPES`` (bench/archs): an op
+  counts under each of them on its path, and in the mixer if any is, so
+  the mixer cuts across ``fwd`` and ``bwd``.  Time is each op's self
+  time (bench/trace.py), so the phases and OTHER add up to the device's
+  busy time;
 * host spans of the program (``train.*``, ``loader.*``) on the thread
   that ran the window.  Each device-idle interval is split among the
   innermost program spans over it, by overlap; what no program span
@@ -23,12 +26,11 @@ from __future__ import annotations
 
 import re
 from collections import defaultdict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 from bench import trace
 
 PHASES = ("embed", "fwd", "head", "bwd", "optimizer")
-MIXERS = ("attention", "wkv")
 OTHER = "other"
 UNATTRIBUTED = "unattributed"
 PROGRAM_SPAN = re.compile(r"^(?:train|loader)\.")
@@ -62,8 +64,10 @@ def phase_of(op_name: str) -> str:
     return next((p for p in scopes(op_name) if p in PHASES), OTHER)
 
 
-def in_mixer(op_name: str) -> bool:
-    return any(p in MIXERS for p in scopes(op_name))
+def scopes_of(op_name: str, names: Sequence[str]) -> List[str]:
+    """Which of ``names`` are on the op's path."""
+    path = set(scopes(op_name))
+    return [n for n in names if n in path]
 
 
 def idle_by_span(idle: Sequence[trace.Interval],
@@ -84,13 +88,16 @@ def idle_by_span(idle: Sequence[trace.Interval],
 
 
 def reduce(devices: Dict[int, List[Op]],
-           host: List[Tuple[str, float, float]]) -> dict:
+           host: List[Tuple[str, float, float]],
+           mixer: Sequence[str] = ()) -> dict:
     """devices: {id: [(instruction, start_ns, end_ns, op_name)]}; host:
-    the spans of the thread that ran the window.
+    the spans of the thread that ran the window; mixer: the device scopes
+    of the cell's sequence mixer.
 
     Returns seconds inside the window (from the first ``bench.step`` to
     the end of the last), per device and averaged over devices: busy,
-    idle, each phase and OTHER, the mixer, and idle by program span."""
+    idle, each phase and OTHER, each mixer scope and the mixer (ops under
+    any of them), and idle by program span."""
     steps = [(s, e) for n, s, e in host if n == trace.STEP_SPAN]
     if not steps or not devices:
         raise ValueError("the trace holds no bench.step span or no device")
@@ -100,17 +107,21 @@ def reduce(devices: Dict[int, List[Op]],
     for dev, ops in sorted(devices.items()):
         names = {i: o for i, _, _, o in ops}
         phases = dict.fromkeys(PHASES + (OTHER,), 0.0)
-        mixer = 0.0
+        scope = dict.fromkeys(mixer, 0.0)
+        mixer_s = 0.0
         for i, t in trace.self_times([(i, s, e) for i, s, e, _ in ops],
                                      lo, hi).items():
             phases[phase_of(names[i])] += t / 1e9
-            mixer += t / 1e9 if in_mixer(names[i]) else 0.0
+            under = scopes_of(names[i], mixer)
+            for n in under:
+                scope[n] += t / 1e9
+            mixer_s += t / 1e9 if under else 0.0
         busy = trace.union([(s, e) for _, s, e, _ in ops], lo, hi)
         idle = trace.gaps(busy, lo, hi)
         per_device[dev] = {
             "busy_s": trace.length(busy) / 1e9,
             "idle_s": trace.length(idle) / 1e9, "phases": phases,
-            "mixer_s": mixer,
+            "scope_s": scope, "mixer_s": mixer_s,
             "idle_by_span": {n: t / 1e9 for n, t in
                              idle_by_span(idle, spans).items()}}
     n = len(per_device)
@@ -124,24 +135,30 @@ def reduce(devices: Dict[int, List[Op]],
             "idle_s": mean(lambda d: d["idle_s"]),
             "phases": {p: mean(lambda d: d["phases"][p])
                        for p in PHASES + (OTHER,)},
+            "scope_s": {n: mean(lambda d: d["scope_s"][n]) for n in mixer},
             "mixer_s": mean(lambda d: d["mixer_s"]),
             "idle_by_span": {k: mean(lambda d: d["idle_by_span"].get(k, 0.0))
                              for k in sorted(keys)}}
 
 
-def per_step_ms(r: dict, steps: int) -> Dict[str, Optional[float]]:
-    """The per-layer numbers, device milliseconds a window step."""
+def per_step_ms(r: dict, steps: int) -> dict:
+    """The per-layer numbers, device milliseconds a window step, and
+    ``scope_ms``, those under each of the mixer's scopes; no
+    ``mixer_ms`` where the mixer names no scope."""
     if not steps:
         return {}
     ms = lambda s: 1e3 * s / steps
     idle = r["idle_by_span"]
-    return {"fwd_ms": ms(r["phases"]["fwd"]),
-            "bwd_ms": ms(r["phases"]["bwd"]),
-            "head_ms": ms(r["phases"]["head"]),
-            "optimizer_ms": ms(r["phases"]["optimizer"]),
-            "mixer_ms": ms(r["mixer_s"]),
-            "input_idle_ms": ms(sum(idle.get(k, 0.0) for k in INPUT_SPANS)),
-            "dispatch_idle_ms": ms(idle.get(DISPATCH_SPAN, 0.0))}
+    out = {"fwd_ms": ms(r["phases"]["fwd"]),
+           "bwd_ms": ms(r["phases"]["bwd"]),
+           "head_ms": ms(r["phases"]["head"]),
+           "optimizer_ms": ms(r["phases"]["optimizer"]),
+           "input_idle_ms": ms(sum(idle.get(k, 0.0) for k in INPUT_SPANS)),
+           "dispatch_idle_ms": ms(idle.get(DISPATCH_SPAN, 0.0)),
+           "scope_ms": {n: ms(t) for n, t in r["scope_s"].items()}}
+    if r["scope_s"]:
+        out["mixer_ms"] = ms(r["mixer_s"])
+    return out
 
 
 def table(r: dict, steps: int) -> str:
@@ -149,7 +166,9 @@ def table(r: dict, steps: int) -> str:
     lines = [f"window {r['window_s']!r} s, {steps} steps; ms a step:"]
     for dev, d in sorted(r["devices"].items()):
         row = [f"{p} {1e3 * t / steps:.3f}" for p, t in d["phases"].items()]
+        row += [f"{n} {1e3 * t / steps:.3f}" for n, t in d["scope_s"].items()]
         row += [f"mixer {1e3 * d['mixer_s'] / steps:.3f}",
+                f"busy {1e3 * d['busy_s'] / steps:.3f}",
                 f"idle {1e3 * d['idle_s'] / steps:.3f}"]
         lines.append(f"  device {dev}: " + ", ".join(row))
         lines.append("    idle by span: " + ", ".join(
@@ -193,11 +212,3 @@ def with_op_names(devices: Dict[int, list], op_names: Dict[str, str]
     ("" where it has none)."""
     return {d: [(i, s, e, op_names.get(i, "")) for i, s, e in ops]
             for d, ops in devices.items()}
-
-
-def load(trace_dir: str, hlo_text: str) -> Tuple[dict, list]:
-    """(devices, host spans) as :func:`reduce` takes them, from the
-    newest .xplane.pb under trace_dir and the HLO text of the step it
-    ran (``compiled.as_text()``)."""
-    devices, host = trace.load(trace_dir)
-    return with_op_names(devices, hlo_op_names(hlo_text)), host

@@ -21,20 +21,28 @@ follows the semantics the configuration states on one pipeline stage:
   (``param_dtype``, and float32 for ``float32_params``), as the program
   keeps them: each update's step, and the updated weight, are rounded to
   it; everything else, gradients included, is float32;
+* each layer is the configuration's architecture module's ``block``
+  (bench/archs/<mixer>.py), on one row at a time;
+* where a block returns an auxiliary loss (a router's balance loss), a
+  microbatch's objective is its mean token cross-entropy plus
+  ``aux_loss_weight`` times the sum over layers of the block's ``aux``,
+  each the mean over the microbatch's rows; its gradient reaches the
+  layer's weights and the layers below under both semantics above;
 * the reported loss is the mean over microbatches of each microbatch's
-  mean token cross-entropy.
+  mean token cross-entropy, without the auxiliary term (the program's
+  ``loss`` reports it apart, as ``aux``).
 
 Matrix products run at ``Precision.HIGHEST``.  ``fp8=True`` is the
 control, the reference computed in float8 e4m3 where the configuration
 computes in bfloat16: every matrix product's operands, the embedding's
 rows and every layer's output are rounded to e4m3 (a power-of-two scale
-per tensor), gradients passed straight through.  Attention is computed one key-value head
-group at a time and the head's loss in row blocks, so that the
-reference fits beside nothing else on one chip.
+per tensor), gradients passed straight through.  The head's loss is
+computed in row blocks, and an architecture module keeps its own largest
+intermediate small (attention one key-value head group at a time), so
+that the reference fits beside nothing else on one chip.
 """
 from __future__ import annotations
 
-import math
 import statistics
 from typing import Dict, List
 
@@ -42,12 +50,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import CellFailed, archs, weights
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
-WKV_CHUNK = 64
 HEAD_BLOCKS = 4
 
 
@@ -75,133 +82,6 @@ def layernorm(x, scale, bias, eps):
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
-
-
-def rope(x, theta):
-    """Rotate-half rotary embedding of (S, heads, dh) at positions 0..S-1."""
-    s, _, dh = x.shape
-    inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=F32) / dh)
-    ang = jnp.arange(s, dtype=F32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-# ------------------------------------------------------------------ blocks
-
-def attn_block(cfg, dot, p, x):
-    """Pre-norm GQA attention with RoPE and a causal window, then SwiGLU."""
-    eps = cfg["rms_norm_eps"]
-    s, d = x.shape
-    h = rmsnorm(x, p["norm1"]["scale"], eps)
-    a = p["attn"]
-    q = rope(dot("sd,dhk->shk", h, a["wq"]), cfg["rope_theta"])
-    k = rope(dot("sd,dhk->shk", h, a["wk"]), cfg["rope_theta"])
-    v = dot("sd,dhk->shk", h, a["wv"])
-    kv, dh = k.shape[1], k.shape[2]
-    g = q.shape[1] // kv
-    i = jnp.arange(s)
-    back = i[:, None] - i[None, :]
-    window = cfg.get("sliding_window") or s
-    mask = (back >= 0) & (back < window)
-
-    def group(args):
-        qq, kk, vv = args                       # (S, g, dh), (S, dh) x2
-        sc = dot("sgd,td->gst", qq, kk) / math.sqrt(dh)
-        pr = jax.nn.softmax(jnp.where(mask, sc, -jnp.inf), axis=-1)
-        return dot("gst,td->sgd", pr, vv)
-
-    o = jax.lax.map(jax.checkpoint(group),
-                    (q.reshape(s, kv, g, dh).transpose(1, 0, 2, 3),
-                     k.transpose(1, 0, 2), v.transpose(1, 0, 2)))
-    o = o.transpose(1, 0, 2, 3).reshape(s, kv * g * dh)
-    x = x + dot("sk,kd->sd", o, a["wo"])
-    h = rmsnorm(x, p["norm2"]["scale"], eps)
-    m = p["mlp"]
-    up = jax.nn.silu(dot("sd,df->sf", h, m["w1"])) * dot("sd,df->sf", h,
-                                                         m["w3"])
-    return x + dot("sf,fd->sd", up, m["w2"])
-
-
-def wkv(r, k, v, logw, u):
-    """RWKV-6 WKV, exact, over (S, H, dh) inputs with log-decays logw.
-
-      S_t = diag(w_t) S_{t-1} + k_t v_t^T
-      y_t = r_t . (S_{t-1} + diag(u) k_t v_t^T)
-
-    Chunks of up to WKV_CHUNK positions; every decay factor is exp of a sum of
-    log-decays that is <= 0, so nothing overflows.
-    """
-    s, h, dh = r.shape
-    c = math.gcd(WKV_CHUNK, s)
-    rs = lambda a: a.reshape(s // c, c, h, dh)
-    cum = jnp.cumsum(rs(logw), axis=1)              # sum over tau <= t
-    excl = cum - rs(logw)                           # sum over tau < t
-    t = jnp.arange(c)
-    before = (t[:, None] > t[None, :])[:, :, None, None]
-    ein = lambda eq, *a: jnp.einsum(eq, *a, precision=HIGHEST)
-
-    def chunk(state, inp):
-        rb, kb, vb, cb, eb = inp                    # (C, H, dh)
-        y = ein("thi,hij->thj", rb * jnp.exp(eb), state)
-        dec = jnp.exp(jnp.where(before, eb[:, None] - cb[None, :], -jnp.inf))
-        att = ein("thi,shi,tshi->hts", rb, kb, dec)
-        y = y + ein("hts,shj->thj", att, vb)
-        y = y + ein("thi,thi->th", rb, u * kb)[..., None] * vb
-        last = cb[-1]
-        state = jnp.exp(last)[..., None] * state + ein(
-            "shi,shj->hij", kb * jnp.exp(last[None] - cb), vb)
-        return state, y
-
-    _, ys = jax.lax.scan(jax.checkpoint(chunk), jnp.zeros((h, dh, dh), F32),
-                         (rs(r), rs(k), rs(v), cum, excl))
-    return ys.reshape(s, h, dh)
-
-
-def rwkv_block(cfg, dot, p, x):
-    """RWKV-6 time-mix then channel-mix, each pre-LayerNorm."""
-    eps, gn_eps = cfg["layer_norm_epsilon"], cfg["group_norm_epsilon"]
-    s, d = x.shape
-    dh = cfg["head_size"]
-    nh = d // dh
-    shift = lambda a: jnp.concatenate([jnp.zeros((1, d), F32), a[:-1]])
-    t = p["tmix"]
-    h = layernorm(x, p["norm1"]["scale"], p["norm1"]["bias"], eps)
-    dx = shift(h) - h
-    low = jnp.tanh(dot("sd,dr->sr", h + dx * t["maa_x"], t["tmix_w1"]))
-    mids = dot("sfr,frd->sfd", low.reshape(s, 5, -1), t["tmix_w2"])
-    xw, xk, xv, xr, xg = (h + dx * (t[f"maa_{n}"] + mids[:, i])
-                          for i, n in enumerate("wkvrg"))
-    r = dot("sd,de->se", xr, t["wr"]).reshape(s, nh, dh)
-    k = dot("sd,de->se", xk, t["wk"]).reshape(s, nh, dh)
-    v = dot("sd,de->se", xv, t["wv"]).reshape(s, nh, dh)
-    g = jax.nn.silu(dot("sd,de->se", xg, t["wg"]))
-    dec = t["w0"] + dot("sr,rd->sd", jnp.tanh(dot("sd,dr->sr", xw,
-                                                  t["decay_w1"])),
-                        t["decay_w2"])
-    y = wkv(r, k, v, -jnp.exp(dec).reshape(s, nh, dh), t["u"].reshape(nh, dh))
-    mu = jnp.mean(y, -1, keepdims=True)
-    var = jnp.mean((y - mu) ** 2, -1, keepdims=True)
-    y = ((y - mu) * jax.lax.rsqrt(var + gn_eps)).reshape(s, d)
-    y = y * t["gn_scale"] + t["gn_bias"]
-    x = x + dot("sd,de->se", y * g, t["wo"])
-    c = p["cmix"]
-    h = layernorm(x, p["norm2"]["scale"], p["norm2"]["bias"], eps)
-    dx = shift(h) - h
-    kk = jnp.square(jax.nn.relu(dot("sd,df->sf", h + dx * c["maa_k"],
-                                    c["wk"])))
-    gate = jax.nn.sigmoid(dot("sd,de->se", h + dx * c["maa_r"],
-                              c["wr_gate"]))
-    return x + gate * dot("sf,fd->sd", kk, c["wv"])
-
-
-BLOCKS = {"attn": attn_block, "rwkv": rwkv_block}
-
-
-def final_norm(cfg, p, x):
-    if cfg["mixer"] == "rwkv":
-        return layernorm(x, p["scale"], p["bias"], cfg["layer_norm_epsilon"])
-    return rmsnorm(x, p["scale"], cfg["rms_norm_eps"])
 
 
 # ------------------------------------------------------------------ layout
@@ -277,18 +157,55 @@ class Reference:
                 "pp > 1 delays weight versions between stages")
         self.cfg = cfg
         dot = make_dot(fp8)
-        block = BLOCKS[cfg["mixer"]]
+        arch = archs.load(cfg)
+        self.kinds = [arch.layer_kind(cfg, i)
+                      for i in range(cfg["num_hidden_layers"])]
+        weight = cfg.get("aux_loss_weight")
         opt = cfg["optimizer"]
         # weights stay in the dtype they are kept in; every computation,
         # and every gradient, is float32
         act = fake_fp8 if fp8 else (lambda x: x)
-        layer = jax.vmap(lambda p, x: act(block(cfg, dot, p, x)), (None, 0))
 
-        def layer_bwd(p, m, v, x, g, t):
-            _, vjp = jax.vjp(layer, f32(p), x)
-            dp, dx = vjp(g)
-            p, m, v = tree_adam(opt, p, dp, m, v, t)
-            return p, m, v, dx
+        def rows(i):
+            """Layer i, and every layer of its kind, over a microbatch's
+            rows: (x, aux per row or None)."""
+            def one(p, x):
+                y, aux = arch.block(cfg, dot, p, x, i)
+                return act(y), aux
+            return jax.vmap(one, (None, 0))
+
+        def back(layer, p, x, g):
+            """(dp, dx) of the microbatch's objective, g = d/dy."""
+            (_, aux), vjp = jax.vjp(layer, f32(p), x)
+            if aux is None:
+                return vjp((g, None))
+            if weight is None:
+                raise CellFailed(
+                    f"a {cfg['mixer']} block returns an auxiliary loss and "
+                    f"the configuration states no aux_loss_weight")
+            return vjp((g, jnp.full(aux.shape, weight / aux.shape[0], F32)))
+
+        def kind(layer):
+            def layer_fwd(p, x):
+                return layer(f32(p), x)[0]
+
+            def layer_bwd(p, m, v, x, g, t):
+                dp, dx = back(layer, p, x, g)
+                p, m, v = tree_adam(opt, p, dp, m, v, t)
+                return p, m, v, dx
+
+            # flush schedules: gradients summed over the round, one update
+            def layer_grad(p, x, g):
+                return back(layer, p, x, g)
+
+            return (jax.jit(layer_fwd),
+                    jax.jit(layer_bwd, donate_argnums=(0, 1, 2)),
+                    jax.jit(layer_grad))
+
+        self.layer_fwd, self.layer_bwd, self.layer_grad = {}, {}, {}
+        for k in dict.fromkeys(self.kinds):
+            (self.layer_fwd[k], self.layer_bwd[k],
+             self.layer_grad[k]) = kind(rows(self.kinds.index(k)))
 
         def head_loss(hp, x, labels):
             d = x.shape[-1]
@@ -297,8 +214,8 @@ class Reference:
 
             def blk(args):
                 xx, ll = args
-                logits = dot("nd,dv->nv", final_norm(cfg, hp["final_norm"],
-                                                     xx), hp["head"])
+                logits = dot("nd,dv->nv", arch.final_norm(
+                    cfg, hp["final_norm"], xx), hp["head"])
                 lse = jax.nn.logsumexp(logits, -1)
                 return jnp.sum(lse - jnp.take_along_axis(
                     logits, ll[:, None], -1)[:, 0])
@@ -315,10 +232,6 @@ class Reference:
         def embed_acc(acc, tokens, g):
             return acc.at[tokens.reshape(-1)].add(g.reshape(-1, g.shape[-1]))
 
-        # flush schedules: gradients summed over the round, one update
-        def layer_grad(p, x, g):
-            return jax.vjp(layer, f32(p), x)[1](g)
-
         def head_grad(hp, x, labels):
             loss, (dhp, dx) = jax.value_and_grad(head_loss, (0, 1))(
                 f32(hp), x, labels)
@@ -331,14 +244,11 @@ class Reference:
         def embed_step(e, m, v, acc, n, t):
             return adam_update(opt, e, acc / n, m, v, t)
 
-        self.layer_fwd = jax.jit(lambda p, x: layer(f32(p), x))
-        self.layer_bwd = jax.jit(layer_bwd, donate_argnums=(0, 1, 2))
         self.head_step = jax.jit(head_step, donate_argnums=(0, 1, 2))
         self.embed_acc = jax.jit(embed_acc, donate_argnums=0)
         self.embed_step = jax.jit(embed_step, donate_argnums=(0, 1, 2))
         self.gather = jax.jit(lambda e, tok: act(e[tok].astype(F32)))
         self.flush = cfg["plan"]["stash_mode"] == "flush"
-        self.layer_grad = jax.jit(layer_grad)
         self.head_grad = jax.jit(head_grad)
         self.add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
                            donate_argnums=0)
@@ -387,9 +297,9 @@ class Reference:
             round_loss = []
             for r in range(used):
                 xs = [self.gather(p["embed"], tokens[r])]
-                for lp in p["layers"][:-1]:
-                    xs.append(self.layer_fwd(lp, xs[-1]))
-                x_top = self.layer_fwd(p["layers"][-1], xs[-1])
+                for k, lp in zip(self.kinds, p["layers"]):
+                    xs.append(self.layer_fwd[k](lp, xs[-1]))
+                x_top = xs.pop()
                 if self.flush:
                     loss, dh, g = self.head_grad(head, x_top, labels[r])
                     hsum = self.add(hsum, dh)
@@ -400,12 +310,12 @@ class Reference:
                 round_loss.append(loss)
                 for i in reversed(range(len(p["layers"]))):
                     if self.flush:
-                        dp, g = self.layer_grad(p["layers"][i], xs[i], g)
+                        dp, g = self.layer_grad[self.kinds[i]](
+                            p["layers"][i], xs[i], g)
                         lsum[i] = self.add(lsum[i], dp)
                         continue
-                    lp, m, v, g = self.layer_bwd(p["layers"][i],
-                                                 *opt["layers"][i],
-                                                 xs[i], g, t)
+                    lp, m, v, g = self.layer_bwd[self.kinds[i]](
+                        p["layers"][i], *opt["layers"][i], xs[i], g, t)
                     p["layers"][i], opt["layers"][i] = lp, (m, v)
                 acc = self.embed_acc(acc, tokens[r], g)
             if self.flush:

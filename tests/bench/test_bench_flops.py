@@ -10,7 +10,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import flops, harness  # noqa: E402
+from bench import archs, flops, harness  # noqa: E402
 
 
 def config(name):
@@ -21,7 +21,8 @@ def config(name):
 def test_danube3_4b_hand_count():
     cfg = config("danube3-4b")
     # per layer: q, o 3840^2 each; k, v 3840*8*120 each; SwiGLU 3*3840*10240
-    assert flops.layer_matmul_params(cfg) == 154_828_800
+    assert all(archs.load(cfg).matmul_params(cfg, i) == 154_828_800
+               for i in range(cfg["num_hidden_layers"]))
     per_token = flops.train_flops_per_token(cfg, 4096)
     assert per_token == pytest.approx(4.84e9, rel=5e-3)
     assert per_token * 8 * 4096 == pytest.approx(1.583e14, rel=1e-3)
@@ -29,11 +30,20 @@ def test_danube3_4b_hand_count():
 
 def test_rwkv6_hand_count():
     cfg = config("rwkv6-1.6b")
-    assert cfg["num_hidden_layers"] * flops.layer_matmul_params(cfg) == \
-        pytest.approx(0.444e9, rel=5e-3)
+    arch = archs.load(cfg)
+    assert sum(arch.matmul_params(cfg, i) for i in range(
+        cfg["num_hidden_layers"])) == pytest.approx(0.444e9, rel=5e-3)
     per_token = flops.train_flops_per_token(cfg, 4096)
     assert per_token == pytest.approx(3.47e9, rel=5e-3)
     assert per_token * 8 * 4096 == pytest.approx(1.14e14, rel=5e-3)
+
+
+@pytest.mark.parametrize("name,per_token", [("danube3-4b", 4830750720.0),
+                                            ("rwkv6-1.6b", 3479175168.0)])
+def test_shipped_configurations_count_as_before(name, per_token):
+    """The values behind the ledger's step_mfu, exactly: the terms moved
+    into bench/archs and are summed layer by layer."""
+    assert flops.train_flops_per_token(config(name), 4096) == per_token
 
 
 def test_pp2tp2_hand_count():

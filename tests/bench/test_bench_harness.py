@@ -72,3 +72,29 @@ def test_program_must_run_the_configuration():
     plan.stash_mode = "2bw"
     with pytest.raises(harness.CellFailed, match="stash_mode"):
         harness.check_build(cell, spec, bundle)
+
+
+def test_rwkv_build_check_reads_its_module():
+    cell = harness.find_cell("rwkv6-1.6b.train-4k")
+    spec = types.SimpleNamespace(d_model=2048, n_layers=8, vocab=65536,
+                                 d_ff=7168,
+                                 rwkv=types.SimpleNamespace(head_dim=64))
+    plan = types.SimpleNamespace(stash_mode="flush", remat=True, pp=1, tp=1)
+    opt = types.SimpleNamespace(lr=5e-4, b1=0.9, b2=0.95, eps=1e-8)
+    bundle = types.SimpleNamespace(sched=types.SimpleNamespace(name="gpipe"),
+                                   plan=plan, optimizer=opt)
+    harness.check_build(cell, spec, bundle)
+    spec.rwkv.head_dim = 32
+    with pytest.raises(harness.CellFailed, match="d_head"):
+        harness.check_build(cell, spec, bundle)
+
+
+def test_launcher_argv_appends_the_configurations_own():
+    cell = harness.find_cell("danube3-4b.train-4k")
+    argv = ["--arch", "h2o-danube3-4b", "--layers", "4", "--pp", "1",
+            "--tp", "1", "--schedule", "gpipe", "--microbatches", "8",
+            "--global-batch", "8", "--seq-len", "4096", "--dtype",
+            "bfloat16"]
+    assert harness.launcher_argv(cell) == argv
+    cell.cfg = dict(cell.cfg, launcher_args=["--vocab-shard", "8"])
+    assert harness.launcher_argv(cell) == argv + ["--vocab-shard", "8"]

@@ -11,9 +11,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import harness, phases, trace  # noqa: E402
+from bench import archs, harness, phases, trace  # noqa: E402
 
 J = "jit(train_step)/while/body/closed_call"
+MIXERS = ("attention", "wkv")
 HOST = [("bench.step", 0, 100),
         ("train.round", 0, 100), ("train.load", 0, 20),
         ("loader.draw", 2, 8), ("loader.place", 8, 18),
@@ -39,12 +40,15 @@ def test_phase_strips_wrappers_and_takes_the_first():
     assert phases.phase_of("") == phases.OTHER
     # a scope name inside another word is no scope
     assert phases.phase_of("jit(f)/forward/bwd_x") == phases.OTHER
-    assert phases.in_mixer(f"{J}/bwd/transpose(jvp(attention))/dot")
-    assert not phases.in_mixer(f"{J}/fwd/mlp/dot")
+    assert phases.scopes_of(f"{J}/bwd/transpose(jvp(attention))/dot",
+                            MIXERS) == ["attention"]
+    assert phases.scopes_of(f"{J}/fwd/mlp/dot", MIXERS) == []
+    # a scope inside another word is no scope
+    assert phases.scopes_of(f"{J}/fwd/attention_bias/dot", MIXERS) == []
 
 
 def test_reduce_hand_built_intervals():
-    r = phases.reduce(DEVICES, HOST)
+    r = phases.reduce(DEVICES, HOST, MIXERS)
     ns = 1e-9
     assert r["window_s"] == pytest.approx(100 * ns)
     assert r["phases"] == {"embed": pytest.approx(4 * ns),
@@ -55,6 +59,8 @@ def test_reduce_hand_built_intervals():
                            "other": pytest.approx(5 * ns)}
     # the mixer cuts across fwd and bwd, attention and wkv alike
     assert r["mixer_s"] == pytest.approx(50 * ns)
+    assert r["scope_s"] == {"attention": pytest.approx(40 * ns),
+                            "wkv": pytest.approx(10 * ns)}
     assert r["busy_s"] == pytest.approx(74 * ns)
     assert r["idle_s"] == pytest.approx(26 * ns)
     assert sum(r["phases"].values()) + r["idle_s"] == \
@@ -87,10 +93,15 @@ def test_idle_outside_program_spans_is_unattributed():
 
 
 def test_per_step_ms():
-    r = phases.reduce(DEVICES, HOST)
+    r = phases.reduce(DEVICES, HOST, MIXERS)
     got = phases.per_step_ms(r, 2)
     assert got["fwd_ms"] == pytest.approx(1e3 * 20e-9 / 2)
     assert got["mixer_ms"] == pytest.approx(1e3 * 50e-9 / 2)
+    assert got["scope_ms"] == {"attention": pytest.approx(1e3 * 40e-9 / 2),
+                               "wkv": pytest.approx(1e3 * 10e-9 / 2)}
+    # a mixer that names no scope has no mixer time
+    bare = phases.per_step_ms(phases.reduce(DEVICES, HOST), 2)
+    assert "mixer_ms" not in bare and bare["scope_ms"] == {}
     assert got["input_idle_ms"] == pytest.approx(1e3 * 20e-9 / 2)
     assert got["dispatch_idle_ms"] == 0.0
     assert phases.per_step_ms(r, 0) == {}
@@ -119,7 +130,11 @@ def test_names_are_the_programs():
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro import obs
     assert phases.PHASES == obs.PHASES
-    assert phases.MIXERS == obs.MIXERS
+    # every architecture module's scopes are the program's mixer scopes
+    for name in sorted(os.listdir(archs.DIR)):
+        if name.endswith(".py") and name != "__init__.py":
+            mod = archs.load({"mixer": name[:-3]})
+            assert mod.SCOPES and set(mod.SCOPES) <= set(obs.MIXERS), name
     assert set(phases.INPUT_SPANS) == {obs.TRAIN_LOAD, *obs.LOADER_SPANS}
     assert phases.DISPATCH_SPAN == obs.TRAIN_DISPATCH
     assert all(phases.PROGRAM_SPAN.match(n)
@@ -140,7 +155,8 @@ def test_recorded_named_chip_step():
         {int(k): [tuple(e) for e in v] for k, v in rec["devices"].items()},
         names)
     host = [tuple(h) for h in rec["host"]]
-    r = phases.reduce(devices, host)
+    scopes = archs.load({"mixer": "attn"}).SCOPES
+    r = phases.reduce(devices, host, scopes)
     assert r["window_s"] == pytest.approx(2.35556733)
     busy, idle = r["busy_s"], r["idle_s"]
     # the phases cover the busy time; phases, other and idle the window
@@ -149,7 +165,7 @@ def test_recorded_named_chip_step():
                                                              rel=1e-2)
     assert all(r["phases"][p] > 0 for p in phases.PHASES)
     # the mixer is attention alone in danube
-    mixer = {i for i, _, _, o in devices[0] if phases.in_mixer(o)}
+    mixer = {i for i, _, _, o in devices[0] if phases.scopes_of(o, scopes)}
     assert mixer and all("attention" in phases.scopes(names[i])
                          for i in mixer)
     assert r["mixer_s"] > 0
@@ -159,7 +175,9 @@ def test_recorded_named_chip_step():
     assert held >= 0.8 * idle
     got = phases.per_step_ms(r, 1)
     assert set(got) == {"fwd_ms", "bwd_ms", "head_ms", "optimizer_ms",
-                        "mixer_ms", "input_idle_ms", "dispatch_idle_ms"}
+                        "mixer_ms", "input_idle_ms", "dispatch_idle_ms",
+                        "scope_ms"}
+    assert got.pop("scope_ms") == {"attention": got["mixer_ms"]}
     assert all(v > 0 for v in got.values())
     text = phases.table(r, 1)
     assert "device 0: embed " in text and "idle by span: " in text
@@ -188,3 +206,28 @@ def test_existing_readers_read_the_same_on_the_existing_step():
         "step_mfu": pytest.approx(100 * 1e9 * 32768 / 2.633775114 / 197e12),
         "hbm_peak_frac": pytest.approx(25.0),
         "compile_s": pytest.approx(3.0)}
+
+
+# device ms a step of the recorded named step (bench/testdata)
+NAMED_STEP_MS = {"fwd_ms": 407.46387500000014, "bwd_ms": 1663.9859240000014,
+                 "head_ms": 184.03395999999987,
+                 "optimizer_ms": 29.149508999999938,
+                 "mixer_ms": 945.5575780000014, "input_idle_ms": 1.57334,
+                 "dispatch_idle_ms": 1.314894}
+
+
+@pytest.mark.parametrize("metric", sorted(NAMED_STEP_MS))
+def test_phase_readers_on_the_recorded_named_step(metric):
+    """Each reader takes its number from record["phases"], as the traced
+    run stores it, and says nothing without it."""
+    rec = recorded("danube3-4b-step-named.json.gz")
+    devices = phases.with_op_names(
+        {int(k): [tuple(e) for e in v] for k, v in rec["devices"].items()},
+        rec["op_names"])
+    r = phases.reduce(devices, [tuple(h) for h in rec["host"]],
+                      archs.load({"mixer": "attn"}).SCOPES)
+    read = harness.metric_reader(metric)
+    assert read({"phases": phases.per_step_ms(r, 1)}) == \
+        pytest.approx(NAMED_STEP_MS[metric], rel=1e-12)
+    assert read({"phases": None}) is None
+    assert read({}) is None
